@@ -27,10 +27,11 @@ steps, emitted under ``nonideality_curve`` together with two gate rows
 ``check_bench --require`` pins.
 
 ``--mesh DxM`` runs the analog side sharded over a DATAxMODEL device mesh
-(docs/analog_pipeline.md §Sharding); on a CPU host the benchmark sets the
-host-platform device-count flag for you, so
+(docs/analog_pipeline.md §Sharding); under ``JAX_PLATFORMS=cpu`` the
+benchmark sets the host-platform device-count flag for you, so
 
-    PYTHONPATH=src python benchmarks/analog_train_bench.py --smoke --mesh 2x4
+    JAX_PLATFORMS=cpu PYTHONPATH=src python benchmarks/analog_train_bench.py \
+        --smoke --mesh 2x4
 
 simulates 8 devices in one process.  The sharded run is bit-identical to
 ``--mesh 1x1`` by construction — the interesting outputs are the
@@ -46,9 +47,12 @@ import time
 
 
 def _pre_init_mesh_flag(argv=None):
-    """``--mesh`` needs the host device count set BEFORE jax initialises;
-    peek at argv and extend XLA_FLAGS when the platform has no real
-    multi-device backend configured."""
+    """``--mesh`` on the CPU host needs the host device count set BEFORE
+    jax initialises; peek at argv and extend XLA_FLAGS.  Only under
+    ``JAX_PLATFORMS=cpu``: on an accelerator the mesh takes the real
+    devices."""
+    if os.environ.get("JAX_PLATFORMS") != "cpu":
+        return
     argv = argv if argv is not None else sys.argv[1:]
     for i, a in enumerate(argv):
         mesh = None
@@ -496,6 +500,12 @@ def main(argv=None):
                          "under 'runs' + check_bench-compatible 'rows'")
     ap.add_argument("--out", default="BENCH_analog_train.json")
     args = ap.parse_args(argv)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # A fixed path: the cache key includes it.  (jax reads the
+        # environment variable itself when it is set.)
+        jax.config.update("jax_compilation_cache_dir", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache"))
     _pre_init_mesh_flag(argv)  # no-op unless argv was passed explicitly
     # Smoke-scale models don't need activation remat; it only inflates
     # compile time and recompute for BOTH runs (models/transformer._remat).

@@ -78,12 +78,8 @@ _SMOKE_ARCH = "lm100m"
 
 
 def _jaxpr_types():
-    import jax
-    try:
-        from jax.extend import core as xc  # jax >= 0.5
-        return xc.Jaxpr, xc.ClosedJaxpr
-    except (ImportError, AttributeError):
-        return jax.core.Jaxpr, jax.core.ClosedJaxpr
+    from jax.extend import core as xc
+    return xc.Jaxpr, xc.ClosedJaxpr
 
 
 def _iter_eqns(jaxpr, inside_shard_map: bool = False):
@@ -104,14 +100,12 @@ def _eqn_site(eqn) -> Tuple[Optional[str], Optional[int]]:
     """(repo-relative file, line) of an equation's user frame."""
     try:
         from jax._src import source_info_util as siu
-        frame = siu.user_frame(eqn.source_info)
+        frame = siu.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:
         return None, None
-    line = getattr(frame, "start_line", None) \
-        or getattr(frame, "line_num", None)
-    return relativize(getattr(frame, "file_name", None)), line
+    return relativize(frame.file_name), frame.start_line
 
 
 def check_no_f64(closed, entry: str) -> List[Finding]:
@@ -160,7 +154,7 @@ def check_clip_round_budget(closed, entry: str,
     pjit_wrapped: Dict[str, int] = {}
     for eqn, _ in _iter_eqns(closed.jaxpr):
         n_eqns += 1
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             sub = str(eqn.params.get("name", ""))
             if sub in ("clip", "round", "_clip", "_round", "amin", "amax"):
                 pjit_wrapped[sub] = pjit_wrapped.get(sub, 0) + 1

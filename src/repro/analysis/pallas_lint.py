@@ -66,6 +66,16 @@ def _as_list(x) -> list:
     return list(x) if isinstance(x, (list, tuple)) else [x]
 
 
+def _spec_info(spec, shape, role: str) -> SpecInfo:
+    """A BlockSpec with no block shape (a whole-array operand, e.g. the
+    per-layer scalars in SMEM) is one block at the origin for every grid
+    point."""
+    shape = tuple(shape)
+    if spec.block_shape is None:
+        return SpecInfo(shape, lambda *_: (0,) * len(shape), shape, role)
+    return SpecInfo(tuple(spec.block_shape), spec.index_map, shape, role)
+
+
 def capture_pallas_calls(fn: Callable, *args, entry: str = "<fn>",
                          **kwargs) -> List[PallasCapture]:
     """Trace ``fn(*args)`` under ``eval_shape`` with ``pl.pallas_call``
@@ -85,15 +95,11 @@ def capture_pallas_calls(fn: Callable, *args, entry: str = "<fn>",
             specs: List[SpecInfo] = []
             in_specs = _as_list(kw.get("in_specs"))
             for i, (spec, op) in enumerate(zip(in_specs, operands)):
-                specs.append(SpecInfo(tuple(spec.block_shape),
-                                      spec.index_map,
-                                      tuple(op.shape), f"in[{i}]"))
+                specs.append(_spec_info(spec, op.shape, f"in[{i}]"))
             out_specs = _as_list(kw.get("out_specs"))
             out_shapes = _as_list(kw.get("out_shape"))
             for i, (spec, sh) in enumerate(zip(out_specs, out_shapes)):
-                specs.append(SpecInfo(tuple(spec.block_shape),
-                                      spec.index_map,
-                                      tuple(sh.shape), f"out[{i}]"))
+                specs.append(_spec_info(spec, sh.shape, f"out[{i}]"))
             name = getattr(kernel, "func", kernel)  # partial -> func
             name = getattr(name, "__name__", str(name))
             captured.append(PallasCapture(entry, name, grid, specs))

@@ -28,10 +28,10 @@ Array = jax.Array
 
 
 def _clip(x: Array, lo, hi) -> Array:
-    """Primitive-level clip.  ``jnp.clip``/``jnp.round`` are pjit-wrapped
-    in jax 0.4.x and the analog sim chain hits them dozens of times per
-    step — raw min/max (and ``lax.round`` below) keep the traced graph
-    flat, which measurably cuts the train step's trace+compile time."""
+    """Primitive-level clip.  ``jnp.clip``/``jnp.round`` are jit-wrapped
+    calls and the analog sim chain hits them dozens of times per step —
+    raw min/max (and ``lax.round`` below) keep the traced graph flat,
+    which measurably cuts the train step's trace+compile time."""
     return jnp.minimum(jnp.maximum(x, lo), hi)
 
 
@@ -107,19 +107,34 @@ def integrator_saturation(q: Array, cfg: AdcConfig, n_rows: int,
     ``q`` and is consumed by :func:`adc_quantize` as the ADC full scale.
     """
     if cfg.range_mode == "fixed":
-        full_scale = cfg.in_levels * n_rows * g_max
-        sat = jnp.asarray(cfg.sat_frac * full_scale, dtype=q.dtype)
+        sat = jnp.asarray(integrator_range(None, None, cfg, n_rows, g_max),
+                          dtype=q.dtype)
     else:  # dynamic: k-sigma of the observed charge
         if reduce_axes is None:
             reduce_axes = tuple(range(q.ndim))
-        # Tiles at the matrix edge contain zero-padded columns; normalising
-        # by the *non-zero* population keeps the range tied to real signal.
         sumsq = jnp.sum(jnp.square(q), axis=reduce_axes, keepdims=True)
         nz = jnp.sum((q != 0).astype(q.dtype), axis=reduce_axes,
                      keepdims=True)
-        rms = jnp.sqrt(sumsq / jnp.maximum(nz, 1.0))
-        sat = jnp.maximum(cfg.sat_sigmas * rms, 1e-6).astype(q.dtype)
+        sat = integrator_range(sumsq, nz, cfg, n_rows,
+                               g_max).astype(q.dtype)
     return _clip(q, -sat, sat), sat
+
+
+def integrator_range(sumsq, nz, cfg: AdcConfig, n_rows: int,
+                     g_max: float = 1.0):
+    """The integrator's saturation level, shared by one population of
+    column charges, from its statistics: ``sumsq`` (sum of squared
+    charge) and ``nz`` (count of nonzero charges).  ``fixed`` mode ignores
+    both and returns the capacitor-sized constant.  A kernel that
+    accumulates the statistics block by block applies the same rule.
+
+    Tiles at the matrix edge contain zero-padded columns (and a kernel's
+    batch block zero-padded rows); normalising by the *non-zero*
+    population keeps the range tied to real signal."""
+    if cfg.range_mode == "fixed":
+        return cfg.sat_frac * (cfg.in_levels * n_rows * g_max)
+    rms = jnp.sqrt(sumsq / jnp.maximum(nz, 1.0))
+    return jnp.maximum(cfg.sat_sigmas * rms, 1e-6)
 
 
 def adc_quantize(q: Array, sat: Array, cfg: AdcConfig,

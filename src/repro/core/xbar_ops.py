@@ -74,7 +74,8 @@ def _tiled_read(x_int: Array, diff: Array, cfg: CrossbarConfig,
     dt = diff.reshape(tk, rows, tn, cols)
     # Per-tile analog column charge:  (B, tk, tn, cols)
     q = jnp.einsum("btr,trnc->btnc", xt.astype(jnp.float32),
-                   dt.astype(jnp.float32))
+                   dt.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
     # One integrator range per physical tile, shared over batch and columns.
     q, sat = integrator_saturation(q, cfg.adc, n_rows=rows,
                                    g_max=cfg.device.gmax,

@@ -18,11 +18,14 @@ re-stacking the results.  Containers with richer lead dims ride the same
 grid: the registry (``core/analog_registry.flatten_lead``) flattens an
 MoE expert stack ``(L, E, K, N)`` expert-outermost onto the layer axis —
 the rank-k write of layers x experts is still one launch — and collapses
-the per-application tape dim of reused weight sets into the batch axis.  Per-layer scalars (the folded ``-lr * w_scale``
-and the PRNG seed) ride in as (L, 1)/(1, 1) blocks indexed by the layer
-grid coordinate.  The output block doubles as the outer-product accumulator
+the per-application tape dim of reused weight sets into the batch axis.
+Per-layer scalars (the folded ``-lr * w_scale``, and the PRNG seed with
+its tile offsets) ride in whole as SMEM operands indexed by the layer grid
+coordinate.  The output block doubles as the outer-product accumulator
 until the last batch step, when the device epilogue transforms it into the
-new conductances in place.
+new conductances in place, one row strip at a time (the in-kernel normals
+and the epilogue's temporaries stay strip-sized, and so does the compiled
+body).
 
 Stochasticity
 -------------
@@ -31,11 +34,14 @@ Three modes (``noise_mode``):
 * ``"none"``   — noiseless devices; no noise operand at all.
 * ``"kernel"`` — the default for training: standard normals are generated
   *inside* the epilogue by a counter-based PRNG (murmur-mix of
-  (seed, layer, tile, cell) + Box–Muller) seeded per (layer, tile) from one
+  (seed, layer, tile, cell pair) + Box–Muller, the two legs of a pair on
+  rows ``r`` and ``r + rows/2``) seeded per (layer, tile) from one
   scalar.  No (K, N) noise field ever exists in HBM, and because the
-  generator is plain uint32/f32 arithmetic it produces bit-identical
-  samples in the compiled TPU kernel, in interpret mode, and in the fused
-  jnp path below — one seed, same conductances on every backend.
+  generator is plain uint32/f32 arithmetic with no carried state it
+  produces the same samples in the compiled TPU kernel, in interpret
+  mode, and in the fused jnp path below — one seed, the same noise on
+  every backend (bit for bit wherever the transcendentals are the same
+  code).
 * ``"host"``   — the legacy pre-generated N(0,1) field rides in as an
   input; kept as the fallback that reproduces ``core.device.apply_update``
   exactly for a given ``jax.random`` key (the kernel-vs-reference
@@ -48,9 +54,14 @@ Switch matrix (every ``impl`` x ``noise_mode`` pair is valid):
     "interpret"         oracle        oracle + ctr PRNG   oracle + field
     "fused"             jnp twin      jnp + field_normals jnp + field
 
-All nine cells produce bit-identical conductances for the same operands
-(and, for "kernel", the same scalar seed) — the PRNG is plain uint32/f32
-arithmetic with no carried state, so the backend cannot reorder it.
+"interpret" and "fused" generate bit-identical noise from the same seed
+and agree on the conductances to f32 rounding
+(tests/test_update_fusion.py).  So does the compiled kernel on a TPU v5e:
+its batch sum associates by token block, and its transcendentals need
+not match XLA's, so a pulse-train event count can round the other way
+(max |kernel - twin| 9.2e-6 for "outer", one pulse in 1.3e-5 of the
+cells for "pulse_train", on the full-width lm100m wqkv stack —
+chip_smoke.py).
 
 Sharding
 --------
@@ -84,10 +95,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec as P
 
 from repro.core.crossbar import CrossbarConfig
 from repro.core.device import DeviceConfig
+from repro.kernels.xbar_vmm import VMEM_LIMIT_CAP
 
 Array = jax.Array
 
@@ -99,6 +112,8 @@ IMPLS = ("auto", "pallas", "interpret", "fused")
 # magnitudes are accumulated separately and quantised to integer
 # clock-cycle counts before the asymmetric device responds to each train.
 UPDATE_MODES = ("outer", "pulse_train")
+# Full f32 contractions on every backend (see kernels/xbar_vmm.py).
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -134,36 +149,37 @@ def _pair_normals(h: Array) -> tuple:
     word supplies both uniforms (16 bits each — radius resolution 1.5e-5
     truncates at 4.7 sigma, far beyond the device-noise regime).  Pure
     uint32/f32 ops — no carried RNG state — the same hash gives the same
-    samples everywhere."""
+    samples everywhere.  Each 16-bit half goes to f32 through int32 (exact
+    below 2**16; Mosaic has no uint32 -> f32 cast)."""
+    hi = (h >> jnp.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (h & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
     # u1 in (0, 1] keeps the log finite.
-    u1 = ((h >> jnp.uint32(16)).astype(jnp.float32) + 1.0) * (1.0 / (1 << 16))
-    u2 = (h & jnp.uint32(0xFFFF)).astype(jnp.float32) * (1.0 / (1 << 16))
+    u1 = (hi + 1.0) * (1.0 / (1 << 16))
+    u2 = lo * (1.0 / (1 << 16))
     r = jnp.sqrt(-2.0 * jnp.log(u1))
     a = (2.0 * np.pi) * u2
     return r * jnp.cos(a), r * jnp.sin(a)
 
 
-def _tile_normals(seed: Array, rows: int, cols: int) -> Array:
-    """(rows, cols) standard normals for one tile from its scalar seed.
+def _pair_rows(rows: int) -> tuple:
+    """(legs, pair_rows) of a tile's normal field.  A tile with even
+    ``rows`` (every practical array) pairs row ``r`` with ``r + rows/2``:
+    one Box–Muller draw fills both cells, so it does half the hashing and
+    half the logs.  An odd tile spends a full draw per cell and keeps
+    only the cosine leg."""
+    return (2, rows // 2) if rows % 2 == 0 else (1, rows)
 
-    Pairs interleave along the column axis — (r, 2j) and (r, 2j + 1) share
-    one Box–Muller draw — so a tile with even ``cols`` (every practical
-    array) does half the hashing and half the logs.  The odd-``cols``
-    fallback spends a full draw per cell and keeps only the cosine leg.
-    """
-    if cols % 2 == 0:
-        half = cols // 2
-        pid = (jax.lax.broadcasted_iota(jnp.uint32, (rows, half), 0)
-               * jnp.uint32(half)
-               + jax.lax.broadcasted_iota(jnp.uint32, (rows, half), 1))
-        z0, z1 = _pair_normals(_mix32(pid ^ seed))
-        z = jnp.stack([z0, z1], axis=-1)  # (..., rows, half, 2)
-        return z.reshape(*z.shape[:-2], cols)
-    idx = (jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 0)
-           * jnp.uint32(cols)
-           + jax.lax.broadcasted_iota(jnp.uint32, (rows, cols), 1))
-    z0, _ = _pair_normals(_mix32(idx ^ seed))
-    return z0
+
+def _strip_normals(seed: Array, r0, n: int, cols: int, legs: int) -> tuple:
+    """Normals of pair rows ``[r0, r0 + n)`` of one tile: ``legs`` arrays
+    of shape (..., n, cols), leg ``j`` belonging to tile rows
+    ``r0 + j * pair_rows``.  The counter of pair (r, c) is ``r * cols +
+    c``; ``seed`` broadcasts against the (n, cols) strip."""
+    pid = ((jax.lax.broadcasted_iota(jnp.int32, (n, cols), 0) + r0)
+           * cols
+           + jax.lax.broadcasted_iota(jnp.int32, (n, cols), 1))
+    z = _pair_normals(_mix32(pid.astype(jnp.uint32) ^ seed))
+    return z[:legs]
 
 
 def field_normals(seed, shape, cfg: CrossbarConfig,
@@ -185,7 +201,9 @@ def field_normals(seed, shape, cfg: CrossbarConfig,
     ki = jax.lax.broadcasted_iota(jnp.uint32, (lyr, tk, tn), 1) + k0
     ni = jax.lax.broadcasted_iota(jnp.uint32, (lyr, tk, tn), 2) + n0
     seeds = _tile_seed(seed, li, ki, ni)[..., None, None]
-    z = _tile_normals(seeds, rows, cols)  # (L, tk, tn, rows, cols)
+    legs, prow = _pair_rows(rows)
+    z = jnp.concatenate(_strip_normals(seeds, 0, prow, cols, legs),
+                        axis=-2)  # (L, tk, tn, rows, cols)
     z = z.transpose(0, 1, 3, 2, 4).reshape(lyr, tk * rows, tn * cols)
     return z[:, :k, :n]
 
@@ -278,6 +296,16 @@ def _pulse_epilogue(g: Array, acc: Array, a_abs: Array, m, noise:
 # The kernel
 # --------------------------------------------------------------------------
 
+def _strip_rows(prow: int, cols: int) -> int:
+    """Rows per epilogue strip: the largest divisor of ``prow`` that keeps
+    a strip near 32K cells, a multiple of 8 (the sublane tile) where one
+    exists."""
+    cap = max(8, (1 << 15) // cols)
+    divs = [d for d in range(1, min(prow, cap) + 1) if prow % d == 0]
+    aligned = [d for d in divs if d % 8 == 0]
+    return (aligned or divs)[-1]
+
+
 def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
                    noise_mode: str, update_mode: str = "outer"):
     if update_mode == "pulse_train":
@@ -293,9 +321,20 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
     else:
         x_ref, d_ref, g_ref, scale_ref, o_ref = refs
     bstep = pl.program_id(3)
-    # program ids are read at the kernel-body top level: inside a pl.when
-    # branch they would land in a cond jaxpr the interpreter can't lower.
+    # Program ids and SMEM scalars are read at the kernel-body top level:
+    # inside a pl.when branch they would land in a cond jaxpr the
+    # interpreter can't lower.
     lid, kid, nid = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    m = scale_ref[lid]
+    if noise_mode == "kernel":
+        # seed_ref is [base seed, layer/row/col tile offsets].  Offsets are
+        # the shard's global base tile coordinates (zero when unsharded),
+        # so the per-tile PRNG stream is indexed by *global* grid position
+        # and one seed gives the same noise on any mesh.
+        seed = _tile_seed(seed_ref[0],
+                          _u32(lid) + seed_ref[1],
+                          _u32(kid) + seed_ref[2],
+                          _u32(nid) + seed_ref[3])
 
     @pl.when(bstep == 0)
     def _init():
@@ -307,46 +346,74 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
     o_ref[0, :, :] += jax.lax.dot_general(
         x_ref[0, :, :], d_ref[0, :, :],
         dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        preferred_element_type=jnp.float32, precision=_HIGHEST)
     if a_ref is not None:
         a_ref[0, :, :] += jax.lax.dot_general(
             jnp.abs(x_ref[0, :, :]), jnp.abs(d_ref[0, :, :]),
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            preferred_element_type=jnp.float32, precision=_HIGHEST)
 
     @pl.when(bstep == n_bsteps - 1)
     def _apply():
-        dg_req = scale_ref[0, 0] * o_ref[0, :, :]
-        if noise_mode == "kernel":
-            rows, cols = o_ref.shape[-2:]
-            # seed_ref is (1, 4): [base seed, layer/row/col tile offsets].
-            # Offsets are the shard's global base tile coordinates (zero
-            # when unsharded), so the per-tile PRNG stream is indexed by
-            # *global* grid position and one seed gives the same noise on
-            # any mesh.
-            seed = _tile_seed(seed_ref[0, 0],
-                              _u32(lid) + seed_ref[0, 1],
-                              _u32(kid) + seed_ref[0, 2],
-                              _u32(nid) + seed_ref[0, 3])
-            noise = _tile_normals(seed, rows, cols)
-        elif noise_mode == "host":
-            noise = noise_ref[0, :, :]
-        else:
-            noise = None
-        if a_ref is not None:
-            o_ref[0, :, :] = _pulse_epilogue(
-                g_ref[0, :, :], o_ref[0, :, :], a_ref[0, :, :],
-                scale_ref[0, 0], noise, cfg.device)
-        else:
-            o_ref[0, :, :] = _device_epilogue(g_ref[0, :, :], dg_req, noise,
-                                              cfg.device)
+        # The device epilogue runs over row strips, so the in-kernel
+        # normals and the epilogue's temporaries stay strip-sized.  Strip
+        # i covers pair rows [i*s, (i+1)*s); its Box–Muller legs land on
+        # tile rows r and r + rows/2 (_pair_rows).
+        rows, cols = o_ref.shape[-2:]
+        legs, prow = _pair_rows(rows)
+        s = _strip_rows(prow, cols)
+
+        def strip(i, carry):
+            r0 = i * s
+            if noise_mode == "kernel":
+                zs = _strip_normals(seed, r0, s, cols, legs)
+            for j in range(legs):
+                rs = pl.ds(pl.multiple_of(r0 + j * prow, s), s)
+                if noise_mode == "kernel":
+                    noise = zs[j]
+                elif noise_mode == "host":
+                    noise = noise_ref[0, rs, :]
+                else:
+                    noise = None
+                if a_ref is not None:
+                    o_ref[0, rs, :] = _pulse_epilogue(
+                        g_ref[0, rs, :], o_ref[0, rs, :], a_ref[0, rs, :],
+                        m, noise, cfg.device)
+                else:
+                    o_ref[0, rs, :] = _device_epilogue(
+                        g_ref[0, rs, :], m * o_ref[0, rs, :], noise,
+                        cfg.device)
+            return carry
+
+        jax.lax.fori_loop(0, prow // s, strip, 0)
+
+
+def _update_vmem_bytes(b: int, cfg: CrossbarConfig, noise_mode: str,
+                      update_mode: str) -> int:
+    """Scoped VMEM the update kernel needs for a ``b``-token block: the
+    double-buffered tape blocks and tile-sized operand/output blocks, the
+    outer-product temporaries, and 4 MiB of slack for the epilogue strips
+    and Mosaic's own scratch."""
+    tile = cfg.rows * cfg.cols
+    n_tiles = 2 + (noise_mode == "host") + (update_mode == "pulse_train")
+    temps = (1 + (update_mode == "pulse_train")) * tile
+    return (4 * (2 * (b * (cfg.rows + cfg.cols) + n_tiles * tile) + temps)
+            + (4 << 20))
+
+
+# Token block of the update's batch grid axis.  The outer-product sum
+# accumulates in the resident output block across batch steps, so the
+# block size moves only the f32 association; this one keeps the tape
+# blocks at 1 MiB and the compiled body small (Mosaic unrolls the
+# block's matmul).
+UPDATE_BLOCK_B = 256
 
 
 def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
                    noise_mode, interpret, update_mode="outer"):
     lyr, k, n = g.shape
     b = x_q.shape[1]
-    bb = block_b or b
+    bb = block_b or min(b, UPDATE_BLOCK_B)
     x_q = jnp.pad(x_q, ((0, 0), (0, (-b) % bb), (0, (-k) % cfg.rows)))
     d_q = jnp.pad(d_q, ((0, 0), (0, (-b) % bb), (0, (-n) % cfg.cols)))
     gp = jnp.pad(g, ((0, 0), (0, (-k) % cfg.rows), (0, (-n) % cfg.cols)))
@@ -354,38 +421,34 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
     bp = x_q.shape[1]
     grid = (lyr, kp // cfg.rows, np_ // cfg.cols, bp // bb)
 
+    tile_spec = pl.BlockSpec((1, cfg.rows, cfg.cols),
+                             lambda l_, k_, n_, b_: (l_, k_, n_))
     inputs = [x_q, d_q, gp]
     in_specs = [
         pl.BlockSpec((1, bb, cfg.rows), lambda l_, k_, n_, b_: (l_, b_, k_)),
         pl.BlockSpec((1, bb, cfg.cols), lambda l_, k_, n_, b_: (l_, b_, n_)),
-        pl.BlockSpec((1, cfg.rows, cfg.cols),
-                     lambda l_, k_, n_, b_: (l_, k_, n_)),
+        tile_spec,
     ]
     if noise_mode == "host":
-        noisep = jnp.pad(noise, ((0, 0), (0, (-k) % cfg.rows),
-                                 (0, (-n) % cfg.cols)))
-        inputs.append(noisep)
-        in_specs.append(pl.BlockSpec((1, cfg.rows, cfg.cols),
-                                     lambda l_, k_, n_, b_: (l_, k_, n_)))
+        inputs.append(jnp.pad(noise, ((0, 0), (0, (-k) % cfg.rows),
+                                      (0, (-n) % cfg.cols))))
+        in_specs.append(tile_spec)
     elif noise_mode == "kernel":
-        inputs.append(jnp.reshape(
-            jnp.stack([_u32(seed)] + [_u32(o) for o in offs]), (1, 4)))
-        in_specs.append(pl.BlockSpec((1, 4), lambda l_, k_, n_, b_: (0, 0)))
-    inputs.append(jnp.reshape(scale, (lyr, 1)))
-    in_specs.append(pl.BlockSpec((1, 1), lambda l_, k_, n_, b_: (l_, 0)))
+        inputs.append(jnp.stack([_u32(seed)] + [_u32(o) for o in offs]))
+        in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
+    # The per-layer scales sit whole in SMEM, indexed by the layer grid
+    # coordinate inside the body.
+    inputs.append(scale)
+    in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
 
-    g_spec = pl.BlockSpec((1, cfg.rows, cfg.cols),
-                          lambda l_, k_, n_, b_: (l_, k_, n_))
     g_shape = jax.ShapeDtypeStruct((lyr, kp, np_), jnp.float32)
     if update_mode == "pulse_train":
         # The magnitude accumulator is a second output on the identical
         # tile grid; the caller discards it (scratch that outlives bsteps).
-        out_specs = (g_spec, pl.BlockSpec((1, cfg.rows, cfg.cols),
-                                          lambda l_, k_, n_, b_: (l_, k_, n_)))
-        out_shape = (g_shape, jax.ShapeDtypeStruct((lyr, kp, np_),
-                                                   jnp.float32))
+        out_specs = (tile_spec, tile_spec)
+        out_shape = (g_shape, g_shape)
     else:
-        out_specs = g_spec
+        out_specs = tile_spec
         out_shape = g_shape
     out = pl.pallas_call(
         functools.partial(_update_kernel, cfg=cfg, n_bsteps=grid[3],
@@ -394,6 +457,9 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
+            VMEM_LIMIT_CAP,
+            _update_vmem_bytes(bb, cfg, noise_mode, update_mode))),
         interpret=interpret,
     )(*inputs)
     if update_mode == "pulse_train":
@@ -407,14 +473,15 @@ def _fused_update(g, x_q, d_q, scale, noise, seed, offs, cfg, noise_mode,
     the identical epilogue (and, in kernel noise mode, the identical
     counter-PRNG bits).  The fast path on hosts without Mosaic."""
     acc = jnp.einsum("lbk,lbn->lkn", x_q, d_q,
-                     preferred_element_type=jnp.float32)
+                     preferred_element_type=jnp.float32, precision=_HIGHEST)
     if noise_mode == "kernel":
         noise = field_normals(seed, g.shape, cfg, tile_offsets=offs)
     elif noise_mode == "none":
         noise = None
     if update_mode == "pulse_train":
         a_abs = jnp.einsum("lbk,lbn->lkn", jnp.abs(x_q), jnp.abs(d_q),
-                           preferred_element_type=jnp.float32)
+                           preferred_element_type=jnp.float32,
+                           precision=_HIGHEST)
         return _pulse_epilogue(g, acc, a_abs, scale[:, None, None], noise,
                                cfg.device)
     return _device_epilogue(g, scale[:, None, None] * acc, noise,
@@ -577,27 +644,12 @@ def xbar_outer_update_inline(g: Array, x_q: Array, d_q: Array, scale,
 # Sharded update (shard_map over the container tile grid)
 # --------------------------------------------------------------------------
 
-def _shard_map_fn():
-    """jax.shard_map (>= 0.5) or jax.experimental.shard_map (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def _wrap_shard_map(body, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions
-    (check_rep -> check_vma rename; disabled because the bodies use
-    axis_index/psum patterns the static checkers reject or over-restrict)."""
-    sm = _shard_map_fn()
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return sm(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise TypeError("no compatible shard_map signature found")
+    """shard_map with replication checking off: the bodies use
+    axis_index/psum patterns the static checker rejects or
+    over-restricts."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _flat_axis_index(mesh, names) -> Array:

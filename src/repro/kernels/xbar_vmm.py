@@ -32,14 +32,21 @@ materialised transpose exists: ``(L, B/blk_b, K/rows, N/cols)``.
 lead shapes — the expert-batched ``(L, E, K, N)`` MoE stacks — are
 flattened onto the same axis (``core/analog_registry.flatten_lead`` order),
 so the read of layers x experts is still one launch.  Per-matrix scalars
-ride in as an ``(L, 2)`` block ``[x_scale, x_scale / w_scale]`` indexed by
-the lead grid coordinate.
+ride in whole as an ``(L, 2)`` SMEM operand ``[x_scale, x_scale /
+w_scale]``, indexed by the lead grid coordinate inside the body.
 
-VMEM budget at defaults (f32, 1024x1024 tile, blk_b=128): x 512 KB +
-G 4 MB + G_ref 4 MB + out 512 KB + scales ≈ 9 MB < 16 MB.  The legacy
-unfused kernel held only the pre-subtracted difference (5 MB); fusing the
-reference array in costs one extra operand block and removes a full (K, N)
-HBM round-trip per call.
+Inside one grid step the body walks its batch block in ``READ_STRIP``
+token strips, in two passes: integrate each strip's charge into a VMEM
+scratch while accumulating the tile's range statistics, then convert
+the scratch through the ADC into the resident output block.  Mosaic
+unrolls a whole-block matmul, so the strips keep the compiled body (and
+its compile time) independent of the token count.
+
+VMEM at 1024x1024 tiles and a 2048-token block (f32): x 8 MiB + out
+8 MiB, each double-buffered, + G and G_ref 4 MiB each, double-buffered,
++ 8 MiB charge scratch + the difference tile ≈ 68 MiB with slack
+(:func:`_read_vmem_bytes`); each call sets ``vmem_limit_bytes`` to its
+estimate and refuses a block over ``VMEM_LIMIT_CAP``.
 
 Execution paths (``impl``)
 --------------------------
@@ -89,7 +96,8 @@ multiplies.  The enforced contract is therefore:
 
 Dynamic ADC range: one integrator range is calibrated per (tile, batch
 block), so the calibration population matches the reference exactly when
-``block_b >= B`` (the default) — same contract as the update kernel.
+``block_b >= B`` (the default).  Zero-padded token rows integrate zero
+charge, which the nonzero-count statistics ignore.
 """
 from __future__ import annotations
 
@@ -99,9 +107,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.adc import (_clip, _round, adc_quantize,
-                            integrator_saturation, quantize_input)
+                            integrator_range, integrator_saturation,
+                            quantize_input)
 from repro.core.crossbar import CrossbarConfig, pad_to_tiles
 from repro.core.shardctx import (ShardMeta, combine_partials_exact,
                                  current_mesh, replicate_for_exact_reduce,
@@ -110,17 +120,26 @@ from repro.core.shardctx import (ShardMeta, combine_partials_exact,
 Array = jax.Array
 
 READ_IMPLS = ("auto", "pallas", "interpret", "jnp", "chain")
+# Scoped-VMEM ceiling for one kernel: v5e and v6e have 128 MiB of VMEM
+# per core; the rest stays with the compiler.
+VMEM_LIMIT_CAP = 100 * 2**20
+# Every f32 contraction of the read and the write runs at full f32
+# precision on every backend: the TPU's default would round the
+# conductance operand to bfloat16, which the kernel (Mosaic) never does.
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def resolve_read_impl(impl: Optional[str] = None) -> str:
     """Resolve the read execution path (see module docstring).
 
-    ``None``/``"auto"``: ``"jnp"`` under an active mesh context (the twin
-    carries the exact-reduce pins; a compiled kernel cannot), else
-    ``"pallas"`` on TPU and ``"jnp"`` everywhere else.
+    ``None``/``"auto"``: ``"jnp"`` under an active mesh context of more
+    than one device (the twin carries the exact-reduce pins; a compiled
+    kernel cannot), else ``"pallas"`` on TPU and ``"jnp"`` everywhere
+    else.  A one-device mesh has nothing to pin, so it keeps the kernel.
     """
     if impl in (None, "auto"):
-        if current_mesh() is not None:
+        mesh = current_mesh()
+        if mesh is not None and mesh.size > 1:
             return "jnp"
         return "pallas" if jax.default_backend() == "tpu" else "jnp"
     if impl not in READ_IMPLS:
@@ -128,67 +147,101 @@ def resolve_read_impl(impl: Optional[str] = None) -> str:
     return impl
 
 
-def _adc_epilogue(q: Array, cfg: CrossbarConfig, n_rows: int) -> Array:
-    """Integrator saturation + ramp-ADC quantisation of a tile's charge.
+def _charge_stats(q: Array) -> tuple:
+    """(sum of squares, nonzero count) of a block of column charge — the
+    statistics ``core.adc.integrator_range`` turns into a tile's range."""
+    return jnp.sum(jnp.square(q)), jnp.sum((q != 0).astype(jnp.float32))
 
-    Literally ``core.adc.integrator_saturation`` + ``adc_quantize`` with
-    one range shared over the whole block (the batch x columns of one
-    physical tile) — epilogue-vs-reference bit parity holds by
-    construction.
+
+def _adc_epilogue(q: Array, sat, cfg: CrossbarConfig) -> Array:
+    """Integrator saturation + ramp-ADC quantisation of a tile's charge
+    against its range ``sat`` — literally the clip of
+    ``core.adc.integrator_saturation`` followed by ``adc_quantize``."""
+    return adc_quantize(_clip(q, -sat, sat), sat, cfg.adc)
+
+
+# --------------------------------------------------------------------------
+# The fused kernel
+# --------------------------------------------------------------------------
+
+# Tokens per pass of the kernel body's inner loops.  The body walks its
+# batch block in strips so the compiled code stays the size of one strip
+# (Mosaic unrolls a whole-block matmul), whatever the token count.
+READ_STRIP = 256
+
+
+def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
+                 cfg: CrossbarConfig, transpose: bool, n_steps: int,
+                 strip: int):
+    """One (matrix, batch block, output tile, reduction tile) grid step.
+
+    VMM contracts the drive rows of the stored tile; MVM (``transpose``:
+    drive columns, integrate rows) contracts its columns, so no
+    transposed copy exists.  The tile's integrator range is shared by the
+    whole batch block, so the body makes two passes over its token
+    strips: the first integrates each strip's charge into the ``q_ref``
+    scratch and accumulates the range statistics, the second converts it
+    through the ADC into the resident output block.
     """
-    q, sat = integrator_saturation(q, cfg.adc, n_rows=n_rows,
-                                   g_max=cfg.device.gmax)
-    return adc_quantize(q, sat, cfg.adc)
+    # Grid coordinates and the per-matrix scalars are read at the body's
+    # top level: inside a pl.when branch they would land in a cond jaxpr
+    # the interpreter cannot lower.
+    lid, step = pl.program_id(0), pl.program_id(3)
+    x_scale, out_scale = sc_ref[lid, 0], sc_ref[lid, 1]
+    n_rows = cfg.cols if transpose else cfg.rows
+    contract = ((1,), (1,)) if transpose else ((1,), (0,))
+    levels = float(cfg.adc.in_levels)
 
-
-# --------------------------------------------------------------------------
-# The fused kernels
-# --------------------------------------------------------------------------
-
-def _fused_vmm_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, *,
-                      cfg: CrossbarConfig, n_ksteps: int):
-    k = pl.program_id(3)
-
-    @pl.when(k == 0)
+    @pl.when(step == 0)
     def _init():
         o_ref[0, :, :] = jnp.zeros_like(o_ref[0, :, :])
 
-    # Leading edge: DAC temporal coding against the per-matrix full scale.
-    levels = float(cfg.adc.in_levels)
-    xi = _clip(_round(x_ref[0, :, :] / sc_ref[0, 0], None), -levels, levels)
     # Differential pair: the reference column subtracts in-array (VMEM).
     diff = g_ref[0, :, :] - r_ref[0, :, :]
-    q = jnp.dot(xi, diff, preferred_element_type=jnp.float32)
-    o_ref[0, :, :] += _adc_epilogue(q, cfg, n_rows=cfg.rows)
 
-    @pl.when(k == n_ksteps - 1)
+    def rows_of(i):
+        return pl.ds(pl.multiple_of(i * strip, strip), strip)
+
+    def integrate(i, stats):
+        ts = rows_of(i)
+        # Leading edge: DAC temporal coding against the per-matrix scale.
+        xi = _clip(_round(x_ref[0, ts, :] / x_scale, None), -levels, levels)
+        q = jax.lax.dot_general(xi, diff, (contract, ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=_HIGHEST)
+        q_ref[ts, :] = q
+        sumsq, nz = _charge_stats(q)
+        return stats[0] + sumsq, stats[1] + nz
+
+    n_strips = q_ref.shape[0] // strip
+    zero = jnp.zeros((), jnp.float32)
+    sumsq, nz = jax.lax.fori_loop(0, n_strips, integrate, (zero, zero))
+    sat = integrator_range(sumsq, nz, cfg.adc, n_rows, cfg.device.gmax)
+
+    def convert(i, carry):
+        ts = rows_of(i)
+        o_ref[0, ts, :] += _adc_epilogue(q_ref[ts, :], sat, cfg)
+        return carry
+
+    jax.lax.fori_loop(0, n_strips, convert, 0)
+
+    @pl.when(step == n_steps - 1)
     def _rescale():
         # Trailing edge: the digital x_scale / w_scale rescale, applied
         # while the accumulated block is still resident.
-        o_ref[0, :, :] = o_ref[0, :, :] * sc_ref[0, 1]
+        o_ref[0, :, :] = o_ref[0, :, :] * out_scale
 
 
-def _fused_mvm_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, *,
-                      cfg: CrossbarConfig, n_nsteps: int):
-    n = pl.program_id(3)
-
-    @pl.when(n == 0)
-    def _init():
-        o_ref[0, :, :] = jnp.zeros_like(o_ref[0, :, :])
-
-    levels = float(cfg.adc.in_levels)
-    xi = _clip(_round(x_ref[0, :, :] / sc_ref[0, 0], None), -levels, levels)
-    diff = g_ref[0, :, :] - r_ref[0, :, :]
-    # Transpose read: drive columns, integrate rows — contract the column
-    # dimension of the same stored tile (no materialised transpose).
-    q = jax.lax.dot_general(
-        xi, diff, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    o_ref[0, :, :] += _adc_epilogue(q, cfg, n_rows=cfg.cols)
-
-    @pl.when(n == n_nsteps - 1)
-    def _rescale():
-        o_ref[0, :, :] = o_ref[0, :, :] * sc_ref[0, 1]
+def _read_vmem_bytes(b: int, cfg: CrossbarConfig) -> int:
+    """Scoped VMEM the fused read needs for a ``b``-token block: the
+    double-buffered x / G / G_ref / out blocks, the charge scratch, the
+    difference tile, strip temporaries and 4 MiB of slack for Mosaic's
+    own scratch."""
+    tile = cfg.rows * cfg.cols
+    wide = max(cfg.rows, cfg.cols)
+    cells = (2 * (b * (cfg.rows + cfg.cols) + 2 * tile) + b * wide + tile
+             + 4 * READ_STRIP * wide)
+    return 4 * cells + (4 << 20)
 
 
 def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
@@ -198,43 +251,55 @@ def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
     lyr, b = x.shape[0], x.shape[1]
     k, n = g.shape[1], g.shape[2]
     bb = block_b or b
-    drive = cfg.cols if transpose else cfg.rows
+    # Zero token rows integrate zero charge, which the range statistics
+    # ignore, so a block padded to whole strips reads as the batch alone.
+    strip = min(READ_STRIP, -(-bb // 8) * 8)
+    bb = -(-bb // strip) * strip
+    vmem = _read_vmem_bytes(bb, cfg)
+    if vmem > VMEM_LIMIT_CAP:
+        # The dynamic ADC range is calibrated over one batch block, and
+        # matches the reference only when that block is the whole batch:
+        # a larger batch needs the range computed in a separate pass.
+        raise ValueError(
+            f"fused read of a {bb}-token block at {cfg.rows}x{cfg.cols} "
+            f"tiles needs {vmem / 2**20:.0f} MiB of VMEM, over the "
+            f"{VMEM_LIMIT_CAP / 2**20:.0f} MiB cap; read in smaller "
+            f"token batches")
+    drive, out_w = (cfg.cols, cfg.rows) if transpose else (cfg.rows,
+                                                           cfg.cols)
     x = jnp.pad(x, ((0, 0), (0, (-b) % bb), (0, (-x.shape[2]) % drive)))
     gp = jnp.pad(g, ((0, 0), (0, (-k) % cfg.rows), (0, (-n) % cfg.cols)))
     rp = jnp.pad(ref, ((0, 0), (0, (-k) % cfg.rows), (0, (-n) % cfg.cols)))
     _, kp, np_ = gp.shape
     bp = x.shape[1]
+    # Grid (L, B/bb, out tiles, reduction tiles): the reduction runs
+    # innermost so the output block stays resident while partial ADC
+    # results accumulate.  G / G_ref tiles index as (k-tile, n-tile)
+    # whichever way the array is driven.
     if transpose:
         grid = (lyr, bp // bb, kp // cfg.rows, np_ // cfg.cols)
-        kern = functools.partial(_fused_mvm_kernel, cfg=cfg,
-                                 n_nsteps=grid[3])
-        x_spec = pl.BlockSpec((1, bb, cfg.cols),
-                              lambda l_, b_, k_, n_: (l_, b_, n_))
-        o_spec = pl.BlockSpec((1, bb, cfg.rows),
-                              lambda l_, b_, k_, n_: (l_, b_, k_))
+        g_index = lambda l_, b_, o_, r_: (l_, o_, r_)
         out_shape, out_dim = (lyr, bp, kp), k
     else:
         grid = (lyr, bp // bb, np_ // cfg.cols, kp // cfg.rows)
-        kern = functools.partial(_fused_vmm_kernel, cfg=cfg,
-                                 n_ksteps=grid[3])
-        x_spec = pl.BlockSpec((1, bb, cfg.rows),
-                              lambda l_, b_, n_, k_: (l_, b_, k_))
-        o_spec = pl.BlockSpec((1, bb, cfg.cols),
-                              lambda l_, b_, n_, k_: (l_, b_, n_))
+        g_index = lambda l_, b_, o_, r_: (l_, r_, o_)
         out_shape, out_dim = (lyr, bp, np_), n
-    # G / G_ref tile index: (k-tile, n-tile) regardless of drive direction.
-    if transpose:
-        g_index = lambda l_, b_, k_, n_: (l_, k_, n_)
-    else:
-        g_index = lambda l_, b_, n_, k_: (l_, k_, n_)
     g_spec = pl.BlockSpec((1, cfg.rows, cfg.cols), g_index)
-    sc_spec = pl.BlockSpec((1, 2), lambda l_, b_, i_, j_: (l_, 0))
     out = pl.pallas_call(
-        kern,
+        functools.partial(_read_kernel, cfg=cfg, transpose=transpose,
+                          n_steps=grid[3], strip=strip),
         grid=grid,
-        in_specs=[x_spec, g_spec, g_spec, sc_spec],
-        out_specs=o_spec,
+        # The (L, 2) per-matrix scalars sit whole in SMEM, indexed by the
+        # lead grid coordinate inside the body.
+        in_specs=[pl.BlockSpec((1, bb, drive),
+                               lambda l_, b_, o_, r_: (l_, b_, r_)),
+                  g_spec, g_spec,
+                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+        out_specs=pl.BlockSpec((1, bb, out_w),
+                               lambda l_, b_, o_, r_: (l_, b_, o_)),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bb, out_w), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
     )(x, gp, rp, sc)
     return out[:, :b, :out_dim]
@@ -340,13 +405,14 @@ def _tiled_read_twin(x_int: Array, diff: Array, cfg: CrossbarConfig,
         x_int = jnp.pad(x_int, ((0, 0), (0, kp - x_int.shape[1])))
     tk, tn = kp // rows, np_ // cols
     if tk == 1:
-        q = jnp.dot(x_int.astype(jnp.float32), diff.astype(jnp.float32))
+        q = jnp.dot(x_int.astype(jnp.float32), diff.astype(jnp.float32),
+                    precision=_HIGHEST)
         q = q.reshape(b, 1, tn, cols)
     else:
         xt = x_int.reshape(b, tk, rows)
         dt = diff.reshape(tk, rows, tn, cols)
         q = jnp.einsum("btr,trnc->btnc", xt.astype(jnp.float32),
-                       dt.astype(jnp.float32))
+                       dt.astype(jnp.float32), precision=_HIGHEST)
     q, sat = integrator_saturation(q, cfg.adc, n_rows=rows,
                                    g_max=cfg.device.gmax,
                                    reduce_axes=(0, 3))
@@ -535,13 +601,14 @@ def manual_collective_read(x: Array, g: Array, ref: Array, w_scale,
             x_i = jnp.pad(x_i, ((0, 0), (0, kp - x_i.shape[1])))
         tk, tn = kp // rows, np_ // cols
         if gtk == 1:
-            q = jnp.dot(x_i.astype(jnp.float32), diff.astype(jnp.float32))
+            q = jnp.dot(x_i.astype(jnp.float32), diff.astype(jnp.float32),
+                        precision=_HIGHEST)
             q = q.reshape(b, 1, tn, cols)
         else:
             xt = x_i.reshape(b, tk, rows)
             dt = diff.reshape(tk, rows, tn, cols)
             q = jnp.einsum("btr,trnc->btnc", xt.astype(jnp.float32),
-                           dt.astype(jnp.float32))
+                           dt.astype(jnp.float32), precision=_HIGHEST)
         q, sat = integrator_saturation(q, cfg.adc, n_rows=rows,
                                        g_max=cfg.device.gmax,
                                        reduce_axes=(0, 3))

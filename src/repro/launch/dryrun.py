@@ -1,8 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
-                           + os.environ.get("XLA_FLAGS", ""))
-# ^ MUST run before any other import (jax locks device count on first init).
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver builds abstract inputs (ShapeDtypeStructs — no
@@ -21,6 +16,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import time
 import traceback
 from pathlib import Path
@@ -168,6 +164,11 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main():
+    # The production meshes need 512 host-platform devices; jax fixes the
+    # device count when its backend first initialises, which nothing
+    # before this line does.
+    os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
+                               + os.environ.get("XLA_FLAGS", ""))
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

@@ -6,26 +6,10 @@ import jax
 
 
 def make_mesh(shape, axes):
-    """Version-compat mesh construction.
-
-    ``jax.sharding.AxisType`` (and ``make_mesh``'s ``axis_types`` kwarg)
-    only exist on jax >= 0.5; on 0.4.x every axis is Auto already, so the
-    plain call is equivalent.  Very old versions lack ``jax.make_mesh``
-    entirely and get a raw ``Mesh`` over a reshaped device array.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    make = getattr(jax, "make_mesh", None)
-    if make is None:
-        import numpy as np
-        devices = np.asarray(jax.devices()[: int(np.prod(shape))])
-        return jax.sharding.Mesh(devices.reshape(shape), axes)
-    if axis_type is not None:
-        try:
-            return make(shape, axes,
-                        axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:  # AxisType exists but make_mesh predates the kwarg
-            pass
-    return make(shape, axes)
+    """A mesh over the first ``prod(shape)`` devices, every axis Auto
+    (GSPMD propagates shardings through it)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

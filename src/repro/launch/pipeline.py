@@ -25,7 +25,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
@@ -80,9 +79,9 @@ def pipeline_apply(mesh, stage_fn: Callable, stage_params, x: Array,
         return outputs
 
     p_specs = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(p_specs, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(p_specs, P()), out_specs=P(),
+                       check_vma=False)
     out = fn(stage_params, mb)
     return out.reshape(b, *x.shape[1:])
 

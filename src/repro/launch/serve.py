@@ -19,7 +19,9 @@ recalibration sweep are exercised.
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -54,6 +56,10 @@ def main(argv=None):
                     help="advance the analog backend's simulated clock "
                          "this many days before serving")
     args = ap.parse_args(argv)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # audit: allow RA301 -- entry point: persistent compile cache at a fixed path of the checkout (jax reads the env var itself when it is set)
+        jax.config.update("jax_compilation_cache_dir", str(
+            Path(__file__).resolve().parents[3] / ".jax_cache"))
 
     cfg = get_config(args.arch, smoke=args.smoke)
     params = M.init_params(jax.random.PRNGKey(args.seed), cfg)

@@ -10,7 +10,9 @@ gradient compression.
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -42,6 +44,10 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # audit: allow RA301 -- entry point: persistent compile cache at a fixed path of the checkout (jax reads the env var itself when it is set)
+        jax.config.update("jax_compilation_cache_dir", str(
+            Path(__file__).resolve().parents[3] / ".jax_cache"))
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.analog:

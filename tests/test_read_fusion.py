@@ -25,12 +25,13 @@ import pytest
 
 from repro.core import (IDEAL, AdcConfig, CrossbarConfig, make_reference,
                         weights_to_conductance)
-from repro.core.adc import adc_quantize, integrator_saturation
+from repro.core.adc import (adc_quantize, integrator_range,
+                            integrator_saturation)
 from repro.core.xbar_ops import mvm as core_mvm
 from repro.core.xbar_ops import vmm as core_vmm
 from repro.kernels import ops
-from repro.kernels.xbar_vmm import (_adc_epilogue, resolve_read_impl,
-                                    xbar_fused_read)
+from repro.kernels.xbar_vmm import (_adc_epilogue, _charge_stats,
+                                    resolve_read_impl, xbar_fused_read)
 
 # Power-of-two ADC lsb class: sat = 0.03125 * 127 * 16 * gmax keeps the
 # saturation bound and the lsb exact powers of two times gmax, so every
@@ -141,8 +142,29 @@ def test_adc_epilogue_is_the_chain_ops():
     want, sat = integrator_saturation(q, cfg.adc, n_rows=cfg.rows,
                                       g_max=cfg.device.gmax)
     want = adc_quantize(want, sat, cfg.adc)
-    got = _adc_epilogue(q, cfg, n_rows=cfg.rows)
+    got = _adc_epilogue(q, _kernel_range(q, cfg), cfg)
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+def _kernel_range(q, cfg):
+    return integrator_range(*_charge_stats(q), cfg.adc, cfg.rows,
+                            cfg.device.gmax)
+
+
+def test_kernel_dynamic_range_is_the_chain_range():
+    """Dynamic mode: the kernel accumulates the range statistics strip by
+    strip; zero-padded token rows must not move them, and the range must
+    agree with integrator_saturation's whole-block reduce to one
+    rounding."""
+    cfg = CrossbarConfig(rows=16, cols=16, device=IDEAL, adc=AdcConfig())
+    q = 40.0 * jax.random.normal(jax.random.PRNGKey(7), (6, 16))
+    _, sat = integrator_saturation(q, cfg.adc, n_rows=cfg.rows,
+                                   g_max=cfg.device.gmax)
+    padded = jnp.pad(q, ((0, 2), (0, 0)))
+    np.testing.assert_array_equal(np.asarray(_kernel_range(padded, cfg)),
+                                  np.asarray(_kernel_range(q, cfg)))
+    np.testing.assert_allclose(float(_kernel_range(q, cfg)),
+                               float(sat.reshape(())), rtol=1e-6)
 
 
 @pytest.mark.parametrize("lead", [(3,), (2, 2)])
@@ -183,6 +205,24 @@ def test_unknown_impl_raises():
         core_vmm(x, g, ref, ws, cfg, impl="mosaic")
     with pytest.raises(ValueError, match="impl"):
         resolve_read_impl("fused")
+
+
+def test_auto_read_impl_keeps_the_kernel_on_a_one_device_mesh(monkeypatch):
+    """On a TPU, "auto" takes the Mosaic kernel unless a mesh of more than
+    one device is active (whose exact-reduce pins only the twin carries):
+    a one-chip launcher that installs its 1x1 mesh keeps the kernel."""
+    from repro.core import shardctx
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = type("Mesh", (), {"size": 1})()
+    prev = shardctx.get_shard_context()
+    try:
+        assert resolve_read_impl("auto") == "pallas"
+        shardctx.set_shard_context(mesh, None)
+        assert resolve_read_impl("auto") == "pallas"
+        mesh.size = 4
+        assert resolve_read_impl("auto") == "jnp"
+    finally:
+        shardctx.set_shard_context(*prev)
 
 
 def test_fused_read_rejects_mismatched_lead_dims():
