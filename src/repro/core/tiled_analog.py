@@ -154,7 +154,8 @@ def effective_g(p: dict, cfg: CrossbarConfig) -> Array:
     gc = p.get("g_carry")
     if gc is None:
         return p["g"]
-    return p["g"] + (gc - p["ref"]) / cfg.carry_base
+    with jax.named_scope("xbar.carry"):
+        return p["g"] + (gc - p["ref"]) / cfg.carry_base
 
 
 def readout(p: dict, cfg: CrossbarConfig) -> Array:
@@ -195,36 +196,45 @@ def _vmm_any(x: Array, g: Array, ref: Array, w_scale, cfg,
     the read is shard-local and handles lead dims itself; otherwise the
     batched read runs with the shard context suspended — each expert's
     array is read whole on its owner; the GSPMD-exact-reduce pins only
-    apply to tile-sharded single arrays."""
-    if meta is not None:
-        return vmm(x, g, ref, w_scale, cfg, meta=meta)
-    if g.ndim == 2:
-        return vmm(x, g, ref, w_scale, cfg)
-    with suspended_shard_context():
-        # vmm takes the lead dims natively: the fused read flattens them
-        # onto its kernel layer grid (one pallas_call per container on
-        # TPU); the chain oracle vmaps per matrix.
-        return vmm(x, g, ref, w_scale, cfg)
+    apply to tile-sharded single arrays.
+
+    Everything a read does on the device, from the DAC full-scale
+    reduction to the slice after the kernel, is named ``xbar.read``
+    (the write's driver quantisation and kernel ``xbar.write``, the
+    carry blend and sweep ``xbar.carry``): the chip benchmark splits a
+    profiler trace by these names (``benchmarks/chip/scopes.py``)."""
+    with jax.named_scope("xbar.read"):
+        if meta is not None:
+            return vmm(x, g, ref, w_scale, cfg, meta=meta)
+        if g.ndim == 2:
+            return vmm(x, g, ref, w_scale, cfg)
+        with suspended_shard_context():
+            # vmm takes the lead dims natively: the fused read flattens
+            # them onto its kernel layer grid (one pallas_call per
+            # container on TPU); the chain oracle vmaps per matrix.
+            return vmm(x, g, ref, w_scale, cfg)
 
 
 def _mvm_any(d: Array, g: Array, ref: Array, w_scale, cfg,
              meta=None) -> Array:
-    if meta is not None:
-        return mvm(d, g, ref, w_scale, cfg, meta=meta)
-    if g.ndim == 2:
-        return mvm(d, g, ref, w_scale, cfg)
-    with suspended_shard_context():
-        return mvm(d, g, ref, w_scale, cfg)
+    with jax.named_scope("xbar.read"):
+        if meta is not None:
+            return mvm(d, g, ref, w_scale, cfg, meta=meta)
+        if g.ndim == 2:
+            return mvm(d, g, ref, w_scale, cfg)
+        with suspended_shard_context():
+            return mvm(d, g, ref, w_scale, cfg)
 
 
 def _quantize_operands_any(x: Array, d: Array, cfg):
     """Write-driver quantisation, per matrix of a batched container: the
     full-scale calibration of the temporal/voltage coders is per physical
     array, so each expert quantises against its own operand range."""
-    if x.ndim == 2:
-        return quantize_update_operands(x, d, cfg)
-    return jax.vmap(lambda xx, dd: quantize_update_operands(xx, dd, cfg)
-                    )(x, d)
+    with jax.named_scope("xbar.write"):
+        if x.ndim == 2:
+            return quantize_update_operands(x, d, cfg)
+        return jax.vmap(
+            lambda xx, dd: quantize_update_operands(xx, dd, cfg))(x, d)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(6, 7))

@@ -461,6 +461,7 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
             VMEM_LIMIT_CAP,
             _update_vmem_bytes(bb, cfg, noise_mode, update_mode))),
         interpret=interpret,
+        name="xbar_update",
     )(*inputs)
     if update_mode == "pulse_train":
         out = out[0]

@@ -301,6 +301,7 @@ def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
         scratch_shapes=[pltpu.VMEM((bb, out_w), jnp.float32)],
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
+        name="xbar_vmm",
     )(x, gp, rp, sc)
     return out[:, :b, :out_dim]
 

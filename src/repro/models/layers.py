@@ -372,83 +372,84 @@ def attention(p: dict, x: Array, cfg: ModelConfig, *, causal: bool = True,
     is written at ``len`` and attends causally to the filled prefix.  A
     cache with ``positions=None`` and sq > 1 is a fresh full prefill.
     """
-    hd = cfg.resolved_head_dim
-    b, sq = x.shape[0], x.shape[1]
-    append = cache is not None and x_kv is None and (
-        sq == 1 or positions is not None)
-    if "wqkv" in p:  # fused projection (one VMM sweep)
-        nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
-        if x_kv is None:
-            qkv = project(p["wqkv"], x, cfg)
-            q = _split_heads(qkv[..., :nq], cfg.n_heads)
-            k_self = _split_heads(qkv[..., nq:nq + nkv], cfg.n_kv_heads)
-            v_self = _split_heads(qkv[..., nq + nkv:], cfg.n_kv_heads)
+    with jax.named_scope("attention"):
+        hd = cfg.resolved_head_dim
+        b, sq = x.shape[0], x.shape[1]
+        append = cache is not None and x_kv is None and (
+            sq == 1 or positions is not None)
+        if "wqkv" in p:  # fused projection (one VMM sweep)
+            nq, nkv = cfg.n_heads * hd, cfg.n_kv_heads * hd
+            if x_kv is None:
+                qkv = project(p["wqkv"], x, cfg)
+                q = _split_heads(qkv[..., :nq], cfg.n_heads)
+                k_self = _split_heads(qkv[..., nq:nq + nkv], cfg.n_kv_heads)
+                v_self = _split_heads(qkv[..., nq + nkv:], cfg.n_kv_heads)
+            else:
+                # Fused cross-attention: ONE wide array serves q (driven by
+                # the x stream) and k/v (driven by the x_kv stream).  Both
+                # streams go through in a single application — concatenated
+                # along tokens — so the taped backward deposits one operand
+                # block per step (a container must not be applied twice); the
+                # unused column blocks of each stream carry zero cotangents
+                # and add nothing to the rank-k write.
+                both = jnp.concatenate([x, x_kv.astype(x.dtype)], axis=1)
+                qkv = project(p["wqkv"], both, cfg)
+                q = _split_heads(qkv[:, :sq, :nq], cfg.n_heads)
+                k_self = _split_heads(qkv[:, sq:, nq:nq + nkv],
+                                      cfg.n_kv_heads)
+                v_self = _split_heads(qkv[:, sq:, nq + nkv:], cfg.n_kv_heads)
         else:
-            # Fused cross-attention: ONE wide array serves q (driven by
-            # the x stream) and k/v (driven by the x_kv stream).  Both
-            # streams go through in a single application — concatenated
-            # along tokens — so the taped backward deposits one operand
-            # block per step (a container must not be applied twice); the
-            # unused column blocks of each stream carry zero cotangents
-            # and add nothing to the rank-k write.
-            both = jnp.concatenate([x, x_kv.astype(x.dtype)], axis=1)
-            qkv = project(p["wqkv"], both, cfg)
-            q = _split_heads(qkv[:, :sq, :nq], cfg.n_heads)
-            k_self = _split_heads(qkv[:, sq:, nq:nq + nkv],
-                                  cfg.n_kv_heads)
-            v_self = _split_heads(qkv[:, sq:, nq + nkv:], cfg.n_kv_heads)
-    else:
-        q = _split_heads(project(p["wq"], x, cfg), cfg.n_heads)
-        k_self = v_self = None
-    kv_src = x if x_kv is None else x_kv
-    if positions is None:
-        positions = jnp.broadcast_to(jnp.arange(sq), (b, sq))
-    if append:
-        # --- decode / chunked prefill: append sq tokens to the cache --------
-        k_new = k_self if k_self is not None else _split_heads(
-            project(p["wk"], x, cfg), cfg.n_kv_heads)
-        v_new = v_self if v_self is not None else _split_heads(
-            project(p["wv"], x, cfg), cfg.n_kv_heads)
-        if use_rope:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k_new = apply_rope(k_new, positions, cfg.rope_theta)
-        idx = cache["len"]  # (B,)
-        k = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
-            c, n, (i, 0, 0)))(cache["k"], k_new.astype(cache["k"].dtype),
-                              idx)
-        v = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
-            c, n, (i, 0, 0)))(cache["v"], v_new.astype(cache["v"].dtype),
-                              idx)
-        if sq == 1:
-            o = _decode_sdpa(q, k, v, idx + 1)
+            q = _split_heads(project(p["wq"], x, cfg), cfg.n_heads)
+            k_self = v_self = None
+        kv_src = x if x_kv is None else x_kv
+        if positions is None:
+            positions = jnp.broadcast_to(jnp.arange(sq), (b, sq))
+        if append:
+            # --- decode / chunked prefill: append sq tokens to the cache ----
+            k_new = k_self if k_self is not None else _split_heads(
+                project(p["wk"], x, cfg), cfg.n_kv_heads)
+            v_new = v_self if v_self is not None else _split_heads(
+                project(p["wv"], x, cfg), cfg.n_kv_heads)
+            if use_rope:
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k_new = apply_rope(k_new, positions, cfg.rope_theta)
+            idx = cache["len"]  # (B,)
+            k = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
+                c, n, (i, 0, 0)))(cache["k"], k_new.astype(cache["k"].dtype),
+                                  idx)
+            v = jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice(
+                c, n, (i, 0, 0)))(cache["v"], v_new.astype(cache["v"].dtype),
+                                  idx)
+            if sq == 1:
+                o = _decode_sdpa(q, k, v, idx + 1)
+            else:
+                o = _cached_sdpa(q, k, v, positions)
+            new_cache = {"k": k, "v": v, "len": idx + sq}
         else:
-            o = _cached_sdpa(q, k, v, positions)
-        new_cache = {"k": k, "v": v, "len": idx + sq}
-    else:
-        if k_self is not None:
-            k, v = k_self, v_self
-        else:
-            k = _split_heads(project(p["wk"], kv_src, cfg),
-                             cfg.n_kv_heads)
-            v = _split_heads(project(p["wv"], kv_src, cfg),
-                             cfg.n_kv_heads)
-        if use_rope and x_kv is None:
-            q = apply_rope(q, positions, cfg.rope_theta)
-            k = apply_rope(k, positions, cfg.rope_theta)
-        o = _chunked_sdpa(q, k, v, causal=causal and x_kv is None)
-        new_cache = None
-        if cache is not None and x_kv is None:
-            # prefill fills the cache
-            pad = cache["k"].shape[1] - k.shape[1]
-            new_cache = {
-                "k": jnp.pad(k.astype(cache["k"].dtype),
-                             ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "v": jnp.pad(v.astype(cache["v"].dtype),
-                             ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "len": jnp.full((b,), k.shape[1], dtype=jnp.int32),
-            }
-    out = project(p["wo"], o.reshape(b, sq, -1), cfg)
-    return out, new_cache
+            if k_self is not None:
+                k, v = k_self, v_self
+            else:
+                k = _split_heads(project(p["wk"], kv_src, cfg),
+                                 cfg.n_kv_heads)
+                v = _split_heads(project(p["wv"], kv_src, cfg),
+                                 cfg.n_kv_heads)
+            if use_rope and x_kv is None:
+                q = apply_rope(q, positions, cfg.rope_theta)
+                k = apply_rope(k, positions, cfg.rope_theta)
+            o = _chunked_sdpa(q, k, v, causal=causal and x_kv is None)
+            new_cache = None
+            if cache is not None and x_kv is None:
+                # prefill fills the cache
+                pad = cache["k"].shape[1] - k.shape[1]
+                new_cache = {
+                    "k": jnp.pad(k.astype(cache["k"].dtype),
+                                 ((0, 0), (0, pad), (0, 0), (0, 0))),
+                    "v": jnp.pad(v.astype(cache["v"].dtype),
+                                 ((0, 0), (0, pad), (0, 0), (0, 0))),
+                    "len": jnp.full((b,), k.shape[1], dtype=jnp.int32),
+                }
+        out = project(p["wo"], o.reshape(b, sq, -1), cfg)
+        return out, new_cache
 
 
 def make_cache(cfg: ModelConfig, batch: int, max_len: int,

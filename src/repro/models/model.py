@@ -135,12 +135,13 @@ def loss_fn(params: dict, batch: Dict[str, Array], cfg: ModelConfig
     # Sharding-friendly CE: one-hot contraction instead of take_along_axis
     # (a gather over the vocab-sharded dim would force an all-gather of the
     # full logits tensor).
-    logits = logits.astype(jnp.float32)
-    lse = jax.nn.logsumexp(logits, axis=-1)
-    true_logit = jnp.sum(
-        logits * jax.nn.one_hot(labels, cfg.vocab, dtype=jnp.float32),
-        axis=-1)
-    loss = jnp.mean(lse - true_logit)
+    with jax.named_scope("head_loss"):
+        logits = logits.astype(jnp.float32)
+        lse = jax.nn.logsumexp(logits, axis=-1)
+        true_logit = jnp.sum(
+            logits * jax.nn.one_hot(labels, cfg.vocab, dtype=jnp.float32),
+            axis=-1)
+        loss = jnp.mean(lse - true_logit)
     total = loss + 0.01 * aux
     return total, {"ce": loss, "aux": aux}
 

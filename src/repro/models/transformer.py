@@ -147,13 +147,18 @@ def _scan_blocks(params, x, body, caches=None, length=None):
     def f(carry, xs):
         lp, cache = xs
         x, aux_sum = carry
-        x, new_cache, aux = body(lp, x, cache)
-        return (x, aux_sum + aux), new_cache
+        with jax.named_scope("layer"):
+            x, new_cache, aux = body(lp, x, cache)
+            return (x, aux_sum + aux), new_cache
 
+    # The scan's own work (slicing and stacking the per-layer trees) is
+    # ``layer_scan``; what a layer computes is ``layer`` or a scope
+    # inside it.
     xs = (params, caches)
-    (x, aux), new_caches = jax.lax.scan(
-        _remat(f), (x, jnp.zeros((), jnp.float32)), xs,
-        length=length)
+    with jax.named_scope("layer_scan"):
+        (x, aux), new_caches = jax.lax.scan(
+            _remat(f), (x, jnp.zeros((), jnp.float32)), xs,
+            length=length)
     return x, new_caches, aux
 
 
@@ -176,19 +181,22 @@ def decoder_init(key: Array, cfg: ModelConfig) -> dict:
 
 
 def _logits(p: dict, x: Array, cfg: ModelConfig) -> Array:
-    x = rmsnorm(p["final_ln"], x, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        # scale keeps init logits O(1) (embeddings are unit-variance)
-        return x.astype(jnp.float32) @ p["embed"].T / (cfg.d_model ** 0.5)
-    return (x @ p["lm_head"]["w"].astype(x.dtype)).astype(jnp.float32)
+    with jax.named_scope("head_loss"):
+        x = rmsnorm(p["final_ln"], x, cfg.norm_eps)
+        if cfg.tie_embeddings:
+            # scale keeps init logits O(1) (embeddings are unit-variance)
+            return x.astype(jnp.float32) @ p["embed"].T \
+                / (cfg.d_model ** 0.5)
+        return (x @ p["lm_head"]["w"].astype(x.dtype)).astype(jnp.float32)
 
 
 def _embed_lookup(p: dict, tokens: Array, cfg: ModelConfig) -> Array:
     """K3 (perf): casting the table to bf16 *before* the gather makes the
     vocab-sharded gather's combine collective run at 2 bytes/elem."""
-    if os.environ.get("REPRO_EMBED_BF16"):
-        return p["embed"].astype(cdtype(cfg))[tokens]
-    return p["embed"][tokens].astype(cdtype(cfg))
+    with jax.named_scope("head_loss"):
+        if os.environ.get("REPRO_EMBED_BF16"):
+            return p["embed"].astype(cdtype(cfg))[tokens]
+        return p["embed"][tokens].astype(cdtype(cfg))
 
 
 def decoder_apply(p: dict, tokens: Array, cfg: ModelConfig, *,

@@ -349,9 +349,10 @@ class AnalogTrainStep:
             # and the sweep is elementwise on the local tile blocks, so it
             # is shard-local under shard_map (no new collectives) and the
             # sharded==unsharded bit-parity contract extends over it.
-            new_params = jax.lax.cond(
-                (state["step"] + 1) % int(cfg.carry_period) == 0,
-                self._carry_sweep, lambda t: t, new_params)
+            with jax.named_scope("xbar.carry"):
+                new_params = jax.lax.cond(
+                    (state["step"] + 1) % int(cfg.carry_period) == 0,
+                    self._carry_sweep, lambda t: t, new_params)
         if not rail:
             # Every family maps through the registry now; an empty rail
             # means the tree genuinely carries no containers (a digital
@@ -419,7 +420,9 @@ class AnalogTrainStep:
 
     def _update(self, p, g, key, seed_base, path, rail):
         if is_analog_container(p):
-            return self._update_container(p, g, key, seed_base, path, rail)
+            with jax.named_scope("xbar.write"):
+                return self._update_container(p, g, key, seed_base, path,
+                                              rail)
         if isinstance(p, dict):
             return {k: self._update(p[k], g[k], key, seed_base,
                                     path + (k,), rail)

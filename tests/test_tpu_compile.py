@@ -7,7 +7,9 @@ tests compile each kernel of the analog training step — and the whole
 step — for a *described* v5e (no chip attached) at lm100m's published
 widths on the paper's 1024x1024 tiles, with the token batch of
 ``chip_smoke.py`` (8 x 256).  Nothing runs; a compile that passes is not
-a chip run.
+a chip run.  The compiled step also carries the names the chip
+benchmark splits its profiler trace by: each kernel's ``name`` and each
+layer's ``jax.named_scope``.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process at a time may load the TPU library, and a
@@ -15,6 +17,7 @@ module that loads it while being collected would give each test worker a
 different set of tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,11 @@ CFG = get_config("lm100m").replace(dtype="float32", analog=True,
 XCFG = crossbar_from_model(CFG)          # 1024x1024 tiles, taox, 8/8 bits
 L, K, N = CFG.n_layers, CFG.d_model, 3 * CFG.d_model   # the wqkv stack
 KERNEL = 'custom_call_target="tpu_custom_call"'
+# The kernels' names and the layer scopes of the train step, as
+# benchmarks/chip/scopes.py reads them from a device trace.
+KERNEL_NAMES = ("xbar_vmm", "xbar_update")
+SCOPES = ("xbar.read", "xbar.write", "xbar.carry", "attention",
+          "head_loss", "layer_scan", "layer")
 
 
 @pytest.fixture(scope="module")
@@ -106,10 +114,10 @@ def test_fused_read_refuses_a_block_beyond_vmem(one_chip):
             s(4 * TOKENS, K), s(K, N), s(K, N), s())
 
 
-def test_train_step_compiles_with_kernels(one_chip):
-    """The whole full-width analog step on one chip: every container's
-    read and write is a Mosaic kernel, and the program fits the v5e's
-    16 GB of HBM."""
+@pytest.fixture(scope="module")
+def train_step(one_chip):
+    """The whole full-width analog step compiled for one chip, once for
+    every test that reads it."""
     state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
                          jax.eval_shape(lambda: init_state(
                              jax.random.PRNGKey(0), CFG)))
@@ -117,11 +125,59 @@ def test_train_step_compiles_with_kernels(one_chip):
     key = _spec(one_chip, (2,), jnp.uint32)
     step = make_analog_sgd_step(CFG, lr=0.05, impl="pallas",
                                 read_impl="pallas")
-    c = step._step.lower(state, {"tokens": tok, "labels": tok},
-                         key).compile()
+    return step._step.lower(state, {"tokens": tok, "labels": tok},
+                            key).compile()
+
+
+def test_train_step_compiles_with_kernels(train_step):
+    """The whole full-width analog step on one chip: every container's
+    read and write is a Mosaic kernel, and the program fits the v5e's
+    16 GB of HBM."""
+    c = train_step
     # At least a VMM, an MVM and a write for each of the 4 containers.
     assert c.as_text().count(KERNEL) >= 12
     mem = c.memory_analysis()
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert used < 16e9
+
+
+def _stack_names(names) -> set:
+    """Every name on the given name stacks, transforms unwrapped."""
+    return {t for n in names for t in re.split(r"[/()]", n) if t}
+
+
+def test_train_step_names_its_layers(train_step):
+    """The compiled step's metadata names both kernels and every layer
+    scope the plain step runs (``xbar.carry`` only runs with periodic
+    carry; the lowering test below holds it)."""
+    text = train_step.as_text()
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    for k in KERNEL_NAMES:
+        assert any(f"/{k}/pallas_call" in op for op in ops), k
+    assert set(SCOPES) - {"xbar.carry"} <= _stack_names(ops)
+    for op in ops:
+        if "pallas_call" in op:
+            assert "xbar.read/xbar_vmm/" in op or \
+                "xbar.write/xbar_update/" in op, op
+
+
+def test_lowered_step_names_its_layers():
+    """On the CPU: the test-size step with periodic carry, kernels in
+    interpret mode, lowered (not compiled) carries every kernel name and
+    scope in its debug information."""
+    cfg = get_config("lm100m", smoke=True).replace(
+        dtype="float32", analog=True, analog_mode="device",
+        analog_carry=True, carry_period=4)
+    state = jax.eval_shape(lambda: init_state(jax.random.PRNGKey(0), cfg))
+    tok = jax.ShapeDtypeStruct((2, 16), jnp.int32)
+    step = make_analog_sgd_step(cfg, lr=0.05, impl="interpret",
+                                read_impl="interpret")
+    text = step._step.lower(state, {"tokens": tok, "labels": tok},
+                            jax.ShapeDtypeStruct((2,), jnp.uint32)
+                            ).as_text(debug_info=True)
+    # Name stacks hold a "/"; a bare name is a function's call site.
+    stacks = [n for n in re.findall(r'loc\("([^"]*)"', text) if "/" in n]
+    for k in KERNEL_NAMES:
+        assert any(f"{k}/pallas_call" in n for n in stacks), k
+    assert set(SCOPES) <= _stack_names(stacks)
