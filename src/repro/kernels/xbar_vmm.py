@@ -42,9 +42,24 @@ the scratch through the ADC into the resident output block.  Mosaic
 unrolls a whole-block matmul, so the strips keep the compiled body (and
 its compile time) independent of the token count.
 
-VMEM at 1024x1024 tiles and a 2048-token block (f32): x 8 MiB + out
-8 MiB, each double-buffered, + G and G_ref 4 MiB each, double-buffered,
-+ 8 MiB charge scratch + the difference tile ≈ 68 MiB with slack
+The MXU contraction
+-------------------
+The DAC drive codes are integers of magnitude at most ``in_levels``
+(127 for the paper's 8-bit DAC), which bfloat16 holds exactly up to
+2**8.  Where they fit (:func:`_codes_exact_in_bf16`, a static check of
+the configuration), each grid step splits its difference tile once into
+three bfloat16 parts whose float32 sum is the tile bit for bit
+(:func:`split_bf16x3`) and contracts the bfloat16 codes against each
+part: three bfloat16 MXU passes, every product exact in float32 and
+accumulated in float32.  A float32 ``HIGHEST`` dot would split both
+operands in three and spend six passes, four of them on the codes' zero
+parts, for the same products.  Codes bfloat16 cannot hold (a DAC of 10
+bits or more) keep the ``HIGHEST`` dot.
+
+VMEM at 1024x1024 tiles and a 2048-token block: x 8 MiB + out 8 MiB,
+each double-buffered, + G and G_ref 4 MiB each, double-buffered, + 8 MiB
+charge scratch + the difference tile's three bfloat16 parts (6 MiB;
+4 MiB of float32 on the ``HIGHEST`` path) ≈ 70 MiB with slack
 (:func:`_read_vmem_bytes`); each call sets ``vmem_limit_bytes`` to its
 estimate and refuses a block over ``VMEM_LIMIT_CAP``.
 
@@ -81,12 +96,16 @@ multiplies.  The enforced contract is therefore:
     unconditionally.
   * interpret kernel vs chain — bit-identical in ``fixed`` range mode
     with a power-of-two ADC lsb (arbitrary float data, ragged edge
-    tiles, multi-tile grids, both read directions): the saturation
-    bound is a compile-time constant, every ADC output is an exact
-    integer multiple of a power of two, and all partial sums are exact,
-    so neither FMA contraction nor reduction-order choices can move a
-    bit.  This class exercises every fused stage end to end and is the
-    CI bit-check.  In ``dynamic`` range mode the saturation bound
+    tiles, multi-tile grids, both read directions, both contractions):
+    the saturation bound is a compile-time constant, every ADC output
+    is an exact integer multiple of a power of two, and all partial
+    sums are exact, so neither FMA contraction nor reduction-order
+    choices can move a bit.  This class exercises every fused stage end
+    to end and is the CI bit-check.  On the three-pass path the charge
+    ahead of the ADC sums exact products in another float32 order than
+    the chain's dot, so the two charges can differ in their last bits,
+    and an ADC code only where a charge sits within those bits of a
+    rounding boundary.  In ``dynamic`` range mode the saturation bound
     itself is a data-dependent float reduction (``sumsq`` over the
     calibration block) whose lowering differs between the kernel body
     and the chain's 4-D reduce — bitwise equality across those two
@@ -125,7 +144,9 @@ READ_IMPLS = ("auto", "pallas", "interpret", "jnp", "chain")
 VMEM_LIMIT_CAP = 100 * 2**20
 # Every f32 contraction of the read and the write runs at full f32
 # precision on every backend: the TPU's default would round the
-# conductance operand to bfloat16, which the kernel (Mosaic) never does.
+# conductance operand to bfloat16.  The read kernel's three-pass path
+# contracts bfloat16 operands instead, and keeps every bit of the
+# conductance difference by its exact split.
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
@@ -158,6 +179,27 @@ def _adc_epilogue(q: Array, sat, cfg: CrossbarConfig) -> Array:
     against its range ``sat`` — literally the clip of
     ``core.adc.integrator_saturation`` followed by ``adc_quantize``."""
     return adc_quantize(_clip(q, -sat, sat), sat, cfg.adc)
+
+
+def _codes_exact_in_bf16(adc) -> bool:
+    """Whether bfloat16 holds every DAC drive code exactly: the codes are
+    integers of magnitude up to ``in_levels``, and bfloat16's 8-bit
+    significand holds every integer up to 2**8."""
+    return adc.in_levels <= 256
+
+
+def split_bf16x3(a: Array) -> tuple:
+    """Exact three-part bfloat16 split of a float32 array:
+    ``hi + mid + lo == a`` bit for bit in float32.  Each part takes the
+    next 8 bits of the 24-bit significand and each residual is exact in
+    float32, so nothing is lost while the parts stay clear of float32's
+    overflow and underflow (magnitudes about 2**-100 to 2**100; a
+    conductance difference lies within the device window)."""
+    hi = a.astype(jnp.bfloat16)
+    r1 = a - hi.astype(jnp.float32)
+    mid = r1.astype(jnp.bfloat16)
+    lo = (r1 - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
 
 
 # --------------------------------------------------------------------------
@@ -198,6 +240,24 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
 
     # Differential pair: the reference column subtracts in-array (VMEM).
     diff = g_ref[0, :, :] - r_ref[0, :, :]
+    dims = (contract, ((), ()))
+    if _codes_exact_in_bf16(cfg.adc):
+        # Split once per tile, for the whole token block.
+        parts = split_bf16x3(diff)
+
+        def charge(xi):
+            xb = xi.astype(jnp.bfloat16)
+            # DEFAULT, stated: a float32 model sets JAX's default matmul
+            # precision to HIGHEST, which Mosaic refuses on bf16 operands.
+            hi, mid, lo = (jax.lax.dot_general(
+                xb, p, dims, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT) for p in parts)
+            return hi + (mid + lo)
+    else:
+        def charge(xi):
+            return jax.lax.dot_general(xi, diff, dims,
+                                       preferred_element_type=jnp.float32,
+                                       precision=_HIGHEST)
 
     def rows_of(i):
         return pl.ds(pl.multiple_of(i * strip, strip), strip)
@@ -206,9 +266,7 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
         ts = rows_of(i)
         # Leading edge: DAC temporal coding against the per-matrix scale.
         xi = _clip(_round(x_ref[0, ts, :] / x_scale, None), -levels, levels)
-        q = jax.lax.dot_general(xi, diff, (contract, ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=_HIGHEST)
+        q = charge(xi)
         q_ref[ts, :] = q
         sumsq, nz = _charge_stats(q)
         return stats[0] + sumsq, stats[1] + nz
@@ -234,14 +292,17 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
 
 def _read_vmem_bytes(b: int, cfg: CrossbarConfig) -> int:
     """Scoped VMEM the fused read needs for a ``b``-token block: the
-    double-buffered x / G / G_ref / out blocks, the charge scratch, the
-    difference tile, strip temporaries and 4 MiB of slack for Mosaic's
-    own scratch."""
+    double-buffered x / G / G_ref / out blocks, the charge scratch and
+    strip temporaries (float32), the difference tile (its three bfloat16
+    parts, 6 bytes a cell, where the drive codes are exact in bfloat16,
+    else 4 bytes of float32) and 4 MiB of slack for Mosaic's own
+    scratch."""
     tile = cfg.rows * cfg.cols
     wide = max(cfg.rows, cfg.cols)
-    cells = (2 * (b * (cfg.rows + cfg.cols) + 2 * tile) + b * wide + tile
+    cells = (2 * (b * (cfg.rows + cfg.cols) + 2 * tile) + b * wide
              + 4 * READ_STRIP * wide)
-    return 4 * cells + (4 << 20)
+    diff_bytes = 6 if _codes_exact_in_bf16(cfg.adc) else 4
+    return 4 * cells + diff_bytes * tile + (4 << 20)
 
 
 def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,

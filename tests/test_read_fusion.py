@@ -10,10 +10,13 @@ These tests enforce the contract stated in the module docstring of
     the einsum path (structurally the same program), jit-vs-jit;
   * the interpret-mode Pallas kernel is bit-identical to the chain in
     ``fixed`` range mode with a power-of-two ADC lsb — arbitrary data,
-    ragged edge tiles, multi-tile grids, both read directions (the CI
-    bit-check: every fused stage runs end to end and no FMA contraction
-    or reduction-order choice can move a bit because all partial sums
-    are exact);
+    ragged edge tiles, multi-tile grids, both read directions, on both
+    of the kernel's contractions (three bfloat16 passes against an exact
+    split of ``G - G_ref`` where the drive codes are exact in bfloat16,
+    one float32 ``HIGHEST`` dot where they are not) — the CI bit-check:
+    every fused stage runs end to end and no FMA contraction or
+    reduction-order choice can move a bit because all partial sums are
+    exact;
   * in ``dynamic`` range mode the saturation bound is a data-dependent
     float reduction whose lowering differs between the kernel body and
     the chain's 4-D reduce, so only ~ulp-level agreement is defined.
@@ -23,21 +26,26 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (IDEAL, AdcConfig, CrossbarConfig, make_reference,
-                        weights_to_conductance)
+from repro.core import (IDEAL, TAOX, AdcConfig, CrossbarConfig,
+                        make_reference, weights_to_conductance)
 from repro.core.adc import (adc_quantize, integrator_range,
                             integrator_saturation)
 from repro.core.xbar_ops import mvm as core_mvm
 from repro.core.xbar_ops import vmm as core_vmm
 from repro.kernels import ops
 from repro.kernels.xbar_vmm import (_adc_epilogue, _charge_stats,
-                                    resolve_read_impl, xbar_fused_read)
+                                    resolve_read_impl, split_bf16x3,
+                                    xbar_fused_read, xbar_fused_read_inline)
 
 # Power-of-two ADC lsb class: sat = 0.03125 * 127 * 16 * gmax keeps the
 # saturation bound and the lsb exact powers of two times gmax, so every
 # ADC output is exactly representable and partial sums stay exact.
 POW2_ADC = dict(in_bits=8, out_bits=8, range_mode="fixed",
                 sat_frac=0.03125)
+# The same class with a 10-bit DAC, whose codes (up to 511) bfloat16 does
+# not hold: the kernel keeps its float32 HIGHEST dot.  sat = 0.03125 *
+# 511 * 16 * gmax over 511 ADC levels keeps the lsb at gmax / 2.
+POW2_ADC_10 = dict(POW2_ADC, in_bits=10, out_bits=10)
 
 
 def _setup(k, n, rows=16, cols=16, adc=None, seed=0):
@@ -93,25 +101,34 @@ def test_twin_flat_dot_fastpath_close_to_chain():
 
 # -------------------------------------- interpret kernel vs chain (bitwise)
 
-@pytest.mark.parametrize("k,n,b", [
+def _pow2_cases(shapes):
+    """Each shape with the 8-bit DAC (three bfloat16 passes) under its
+    plain id, then with the 10-bit DAC (the HIGHEST dot)."""
+    ids = ["-".join(map(str, sh)) for sh in shapes]
+    return ([pytest.param(*sh, POW2_ADC, id=i) for sh, i in zip(shapes, ids)]
+            + [pytest.param(*sh, POW2_ADC_10, id=f"{i}-in_bits10")
+               for sh, i in zip(shapes, ids)])
+
+
+@pytest.mark.parametrize("k,n,b,adc", _pow2_cases([
     (16, 16, 4),    # exact single tile
     (40, 24, 6),    # ragged padding on both dims
     (64, 48, 8),    # multi-tile both dims
-])
-def test_interpret_bitwise_chain_fixed_pow2_vmm(k, n, b):
+]))
+def test_interpret_bitwise_chain_fixed_pow2_vmm(k, n, b, adc):
     """The CI bit-check: in the fixed/power-of-two-lsb class the fused
     kernel (DAC, differential subtract, MXU, ADC epilogue, rescale — all
     in one pallas_call) reproduces the chain exactly."""
-    cfg, g, ref, ws = _setup(k, n, adc=POW2_ADC)
+    cfg, g, ref, ws = _setup(k, n, adc=adc)
     x = jax.random.normal(jax.random.PRNGKey(4), (b, k))
     y_chain = core_vmm(x, g, ref, ws, cfg, impl="chain")
     y_ker = core_vmm(x, g, ref, ws, cfg, impl="interpret")
     np.testing.assert_array_equal(np.asarray(y_chain), np.asarray(y_ker))
 
 
-@pytest.mark.parametrize("k,n,b", [(40, 24, 6), (48, 64, 5)])
-def test_interpret_bitwise_chain_fixed_pow2_mvm(k, n, b):
-    cfg, g, ref, ws = _setup(k, n, adc=POW2_ADC)
+@pytest.mark.parametrize("k,n,b,adc", _pow2_cases([(40, 24, 6), (48, 64, 5)]))
+def test_interpret_bitwise_chain_fixed_pow2_mvm(k, n, b, adc):
+    cfg, g, ref, ws = _setup(k, n, adc=adc)
     d = jax.random.normal(jax.random.PRNGKey(5), (b, n))
     y_chain = core_mvm(d, g, ref, ws, cfg, impl="chain")
     y_ker = core_mvm(d, g, ref, ws, cfg, impl="interpret")
@@ -129,6 +146,89 @@ def test_interpret_dynamic_range_ulp_close():
     y_ker = core_vmm(x, g, ref, ws, cfg, impl="interpret")
     np.testing.assert_allclose(np.asarray(y_ker), np.asarray(y_chain),
                                rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------- the kernel's contraction of G - G_ref
+
+def _reconstructs(a, split=split_bf16x3):
+    hi, mid, lo = split(jnp.asarray(a, jnp.float32))
+    assert hi.dtype == mid.dtype == lo.dtype == jnp.bfloat16
+    f32 = lambda p: np.asarray(p, np.float32)
+    np.testing.assert_array_equal(f32(hi) + f32(mid) + f32(lo),
+                                  np.asarray(a, np.float32))
+
+
+@pytest.mark.parametrize("device", [IDEAL, TAOX], ids=["ideal", "taox"])
+def test_bf16_split_is_exact_on_conductance_tiles(device):
+    """hi + mid + lo gives back every bit of a tile's differential
+    conductance: programmed weights and states anywhere in the window."""
+    cfg = CrossbarConfig(rows=1024, cols=1024, device=device)
+    kw, kg = jax.random.split(jax.random.PRNGKey(12))
+    w = jax.random.normal(kw, (1024, 1024)) / 32.0
+    g_prog, _ = weights_to_conductance(w, cfg)
+    g_any = jax.random.uniform(kg, (1024, 1024), minval=device.gmin,
+                               maxval=device.gmax)
+    ref = make_reference((1024, 1024), cfg)
+    for g in (g_prog, g_any):
+        # Eagerly, and traced into one compiled program.
+        for split in (split_bf16x3, jax.jit(split_bf16x3)):
+            _reconstructs(g - ref, split)
+
+
+def test_bf16_split_is_exact_across_exponents():
+    """Both signs, exponents over 2**-100 .. 2**100, full significands."""
+    rng = np.random.default_rng(13)
+    mant = rng.uniform(1.0, 2.0, (256, 256))
+    sign = rng.choice([-1.0, 1.0], (256, 256))
+    a = (sign * mant * 2.0 ** rng.integers(-100, 101, (256, 256))
+         ).astype(np.float32)
+    _reconstructs(a)
+    _reconstructs(np.array([0.0, -0.0, 1.0, -1.0, 2.0 ** -100,
+                            np.nextafter(np.float32(1), np.float32(2))],
+                           np.float32))
+
+
+def _kernel_dots(adc, transpose):
+    """(lhs dtype, rhs dtype, precision) of every dot in the fused read's
+    kernel body, traced as a float32 model traces it: with JAX's default
+    matmul precision at "highest"."""
+    cfg = CrossbarConfig(rows=16, cols=16, device=IDEAL, adc=adc)
+    g = jnp.zeros((40, 24))
+    x = jnp.zeros((5, 24 if transpose else 40))
+    with jax.default_matmul_precision("highest"):
+        jaxpr = jax.make_jaxpr(lambda x_, g_: xbar_fused_read_inline(
+            x_, g_, g_, 1.0, cfg, transpose=transpose,
+            impl="interpret"))(x, g)
+
+    def walk(jx, in_kernel):
+        for eqn in jx.eqns:
+            inner = in_kernel or eqn.primitive.name == "pallas_call"
+            if in_kernel and eqn.primitive.name == "dot_general":
+                prec = eqn.params["precision"]
+                yield (eqn.invars[0].aval.dtype, eqn.invars[1].aval.dtype,
+                       prec[0] if isinstance(prec, tuple) else prec)
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub, inner)
+
+    return list(walk(jaxpr.jaxpr, False))
+
+
+@pytest.mark.parametrize("transpose", [False, True], ids=["vmm", "mvm"])
+@pytest.mark.parametrize("in_bits", [8, 9, 10])
+def test_read_contraction_follows_the_dac_width(in_bits, transpose):
+    """Codes of up to 256 levels (DACs of 8 and 9 bits) are exact in
+    bfloat16 and take three bfloat16 passes; a 10-bit DAC (511 levels)
+    keeps one float32 HIGHEST dot."""
+    dots = _kernel_dots(AdcConfig(in_bits=in_bits), transpose)
+    if in_bits <= 9:
+        assert dots == [(jnp.bfloat16, jnp.bfloat16,
+                         jax.lax.Precision.DEFAULT)] * 3
+    else:
+        assert dots == [(jnp.float32, jnp.float32,
+                         jax.lax.Precision.HIGHEST)]
 
 
 # ----------------------------------------------- epilogue + batched layouts

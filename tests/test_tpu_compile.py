@@ -16,6 +16,7 @@ import time: only one process at a time may load the TPU library, and a
 module that loads it while being collected would give each test worker a
 different set of tests.
 """
+import dataclasses
 import os
 import re
 
@@ -71,14 +72,25 @@ def _spec(sharding, shape, dtype=jnp.float32):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("transpose", [False, True], ids=["vmm", "mvm"])
-def test_fused_read_compiles(one_chip, transpose):
+@pytest.mark.parametrize("transpose,in_bits", [
+    pytest.param(False, 8, id="vmm"), pytest.param(True, 8, id="mvm"),
+    pytest.param(False, 10, id="vmm-in_bits10"),
+    pytest.param(True, 10, id="mvm-in_bits10")])
+def test_fused_read_compiles(one_chip, transpose, in_bits):
+    """Both contractions of the read: three bfloat16 passes for the 8-bit
+    DAC's codes, the float32 HIGHEST dot for a 10-bit DAC's.  A float32
+    model sets JAX's default matmul precision to "highest", which the
+    bfloat16 dots must not take up (Mosaic refuses it)."""
     s = lambda *shape: _spec(one_chip, shape)
     drive = N if transpose else K
-    c = _compile(lambda x, g, r, w: xbar_fused_read_inline(
-        x, g, r, w, XCFG, transpose=transpose, impl="pallas"),
-        s(L, TOKENS, drive), s(L, K, N), s(L, K, N), s(L))
-    assert KERNEL in c.as_text()
+    xcfg = dataclasses.replace(
+        XCFG, adc=dataclasses.replace(XCFG.adc, in_bits=in_bits))
+    for precision in ("default", "highest"):
+        with jax.default_matmul_precision(precision):
+            c = _compile(lambda x, g, r, w: xbar_fused_read_inline(
+                x, g, r, w, xcfg, transpose=transpose, impl="pallas"),
+                s(L, TOKENS, drive), s(L, K, N), s(L, K, N), s(L))
+        assert KERNEL in c.as_text()
 
 
 @pytest.mark.parametrize("noise_mode", ["kernel", "none"])
