@@ -190,7 +190,7 @@ def check_tape_containment(diff, frozen, entry: str) -> List[Finding]:
     def walk_diff(p, path):
         if isinstance(p, dict):
             if "x_tape" in p or "d_tape" in p:
-                leaked = sorted(set(p) - {"x_tape", "d_tape"})
+                leaked = sorted(set(p) - {"x_tape", "d_tape", "n_tape"})
                 if leaked:
                     findings.append(Finding(
                         "RA102", f"tape site {'/'.join(path)} carries "

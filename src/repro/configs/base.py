@@ -79,14 +79,32 @@ class ModelConfig:
     gated: bool = True             # GLU-style FFN (SwiGLU/GeGLU)
     norm_eps: float = 1e-5
     rope_theta: float = 10000.0
+    # YaRN rope scaling (DeepSeek-V2): 0 = plain rope.
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     tie_embeddings: bool = False
     dtype: str = "bfloat16"
 
     # --- MoE ---------------------------------------------------------------
+    # ``n_experts`` counts the experts this model holds; the router scores
+    # ``n_routed_experts`` of them (0: the same number), of which the held
+    # ones are ``first_expert .. first_expert + n_experts - 1`` — one
+    # chip's share of an expert-parallel layer.  ``n_layers`` counts every
+    # layer, the ``n_dense_layers`` leading dense ones included.
     n_experts: int = 0
     top_k: int = 0
     n_shared_experts: int = 0
     d_ff_expert: int = 0
+    n_routed_experts: int = 0
+    first_expert: int = 0
+    norm_topk_prob: bool = True    # renormalise the top-k gates to sum 1
+    n_dense_layers: int = 0
+    # Dispatch capacity of the digital and fakequant paths (device mode
+    # is dropless: each held expert has room for every token).
     capacity_factor: float = 1.25
 
     # --- MLA (DeepSeek-V2) ---------------------------------------------------
@@ -174,6 +192,10 @@ class ModelConfig:
                             analog_mode=AnalogMode.DIGITAL.value)
 
     @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
+
+    @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
 
@@ -223,15 +245,17 @@ class ModelConfig:
             attn = d * hd * (self.n_heads + 2 * self.n_kv_heads) \
                 + self.n_heads * hd * d
         ffn_mult = 3 if self.gated else 2
+        dense = attn + ffn_mult * d * ff
         if self.n_experts:
             ffe = self.d_ff_expert or ff
             n_ffn = (self.top_k if active_only else self.n_experts) \
                 + self.n_shared_experts
             per = attn + n_ffn * ffn_mult * d * ffe \
-                + d * self.n_experts  # + router
+                + d * self.router_width  # + router
         else:
-            per = attn + ffn_mult * d * ff
-        n = self.n_layers * per
+            per = dense
+        n = self.n_dense_layers * dense \
+            + (self.n_layers - self.n_dense_layers) * per
         if self.cross_attn_every:
             n_cross = self.n_layers // self.cross_attn_every
             n += n_cross * (d * hd * (self.n_heads + 2 * self.n_kv_heads)
@@ -285,6 +309,8 @@ def make_smoke(cfg: ModelConfig, **overrides) -> ModelConfig:
     )
     if cfg.n_experts:
         kw.update(n_experts=min(cfg.n_experts, 8),
+                  n_routed_experts=min(cfg.router_width, 8),
+                  first_expert=0,
                   top_k=min(cfg.top_k, 2),
                   d_ff_expert=64 if cfg.d_ff_expert else 0)
     if cfg.use_mla:

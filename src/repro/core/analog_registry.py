@@ -10,8 +10,9 @@ living there:
   * the container's **sharding layout** (which dims carry FSDP / TP / EP
     tile splits, at what granularity),
   * its **tape route**: how many write-driver operand rows the backward
-    pass deposits per step (MoE expert tapes are capacity-sized, shared
-    hybrid blocks tape once per group application), and
+    pass deposits per step (MoE expert tapes hold a dropless buffer of
+    every token per expert, with the routed row counts beside them;
+    shared hybrid blocks tape once per group application), and
   * its **update view**: how the (possibly expert-batched) container
     flattens onto the layer-batched rank-k write grid of
     ``kernels/xbar_update.py`` so the whole stack updates in one
@@ -52,7 +53,8 @@ KINDS = (COLUMN_PARALLEL, ROW_PARALLEL, EXPERT_BATCHED)
 #: ``g_carry`` is the optional periodic-carry LSB array (paper §V.C) —
 #: present only when the config enables carry, shaped and sharded exactly
 #: like ``g``.
-ANALOG_LEAVES = ("g", "ref", "w_scale", "g_carry", "x_tape", "d_tape")
+ANALOG_LEAVES = ("g", "ref", "w_scale", "g_carry", "x_tape", "d_tape",
+                 "n_tape")
 
 #: Projection keys whose K (row) tiles follow the TP axis — the analog
 #: mirror of the digital row-parallel rule.
@@ -140,15 +142,6 @@ def classify_param(path: Sequence) -> Optional[str]:
 # Tape route: operand rows per step and applications per step
 # ---------------------------------------------------------------------------
 
-def expert_capacity(n_tokens: int, cfg) -> int:
-    """Per-expert dispatch capacity (the MoE buffer row count) — also the
-    tape length of an expert-batched container: the write drivers see one
-    operand row per buffer slot, not per token."""
-    c = int(np.ceil(cfg.capacity_factor * n_tokens * cfg.top_k
-                    / cfg.n_experts))
-    return max(8, -(-c // 8) * 8)  # pad to a lane-friendly multiple
-
-
 def tape_reps(path: Sequence, cfg) -> int:
     """How many times the container at ``path`` is applied per step.
 
@@ -197,11 +190,11 @@ def tape_lead(path: Sequence, cfg, n_tokens: int,
     """Shape of one container's tape slots *between* the container's own
     lead dims and the operand feature dim: ``(T,)`` for a once-applied
     container (T from :func:`operand_rows`), ``(reps, T)`` for the shared
-    hybrid block, ``(capacity,)`` per expert for expert-batched
-    containers."""
-    kind = classify(path)
-    if kind == EXPERT_BATCHED:
-        return (expert_capacity(n_tokens, cfg),)
+    hybrid block.  An expert-batched container's slots are ``(T,)`` per
+    expert too: the device-mode dispatch is dropless, each expert's
+    buffer holding up to every token (``models/moe``)."""
+    if classify(path) == EXPERT_BATCHED:
+        return (n_tokens,)
     rows = operand_rows(path, cfg, n_tokens, batch_shape)
     reps = tape_reps(path, cfg)
     return (reps, rows) if reps > 1 else (rows,)
@@ -310,6 +303,17 @@ def flatten_lead(kind: str, g, x_tape, d_tape, scale, noise=None):
         return gg.reshape(g_shape)
 
     return g3, x3, d3, s1, n3, unflatten
+
+
+def flatten_counts(kind: str, v):
+    """Per-matrix values of a container's lead dims (an expert-batched
+    stack's routed row counts, shaped like ``w_scale``) in the flattened
+    layer order of :func:`flatten_lead`."""
+    import jax.numpy as jnp
+    hoist = hoist_axis(kind, v.ndim + 2)
+    if hoist is not None:
+        v = jnp.moveaxis(v, hoist, 0)
+    return v.reshape(-1)
 
 
 # ---------------------------------------------------------------------------

@@ -189,7 +189,7 @@ def _symbolic_zero(x: Array) -> SymbolicZero:
 
 
 def _vmm_any(x: Array, g: Array, ref: Array, w_scale, cfg,
-             meta=None) -> Array:
+             meta=None, rows=None) -> Array:
     """VMM for a plain (K, N) container or an expert-batched (E, K, N)
     stack (x then carries a matching leading dim: one activation batch per
     expert's array).  With a ``meta`` (exact-mode manual-collective read)
@@ -202,7 +202,10 @@ def _vmm_any(x: Array, g: Array, ref: Array, w_scale, cfg,
     reduction to the slice after the kernel, is named ``xbar.read``
     (the write's driver quantisation and kernel ``xbar.write``, the
     carry blend and sweep ``xbar.carry``): the chip benchmark splits a
-    profiler trace by these names (``benchmarks/chip/scopes.py``)."""
+    profiler trace by these names (``benchmarks/chip/scopes.py``).
+
+    ``rows`` (an expert-batched stack's routed row counts, one per
+    expert) lets the kernel skip each expert's zero rows past its count."""
     with jax.named_scope("xbar.read"):
         if meta is not None:
             return vmm(x, g, ref, w_scale, cfg, meta=meta)
@@ -212,18 +215,18 @@ def _vmm_any(x: Array, g: Array, ref: Array, w_scale, cfg,
             # vmm takes the lead dims natively: the fused read flattens
             # them onto its kernel layer grid (one pallas_call per
             # container on TPU); the chain oracle vmaps per matrix.
-            return vmm(x, g, ref, w_scale, cfg)
+            return vmm(x, g, ref, w_scale, cfg, rows=rows)
 
 
 def _mvm_any(d: Array, g: Array, ref: Array, w_scale, cfg,
-             meta=None) -> Array:
+             meta=None, rows=None) -> Array:
     with jax.named_scope("xbar.read"):
         if meta is not None:
             return mvm(d, g, ref, w_scale, cfg, meta=meta)
         if g.ndim == 2:
             return mvm(d, g, ref, w_scale, cfg)
         with suspended_shard_context():
-            return mvm(d, g, ref, w_scale, cfg)
+            return mvm(d, g, ref, w_scale, cfg, rows=rows)
 
 
 def _quantize_operands_any(x: Array, d: Array, cfg):
@@ -237,38 +240,51 @@ def _quantize_operands_any(x: Array, d: Array, cfg):
             lambda xx, dd: quantize_update_operands(xx, dd, cfg))(x, d)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
 def _taped_matmul(g: Array, ref: Array, w_scale: Array,
-                  x_tape: Array, d_tape: Array, x: Array,
+                  x_tape: Array, d_tape: Array, n_tape, rows, x: Array,
                   cfg: CrossbarConfig, meta=None) -> Array:
-    del x_tape, d_tape
-    return _vmm_any(x, g, ref, w_scale, cfg, meta)
+    """y = read(x); its VJP deposits the write operands in the tapes, and
+    for an expert-batched stack its routed row counts ``rows`` in
+    ``n_tape`` (both ``None`` for other containers)."""
+    del x_tape, d_tape, n_tape
+    return _vmm_any(x, g, ref, w_scale, cfg, meta, rows)
 
 
-def _taped_fwd(g, ref, w_scale, x_tape, d_tape, x, cfg, meta):
+def _value(a):
+    return None if a is None else a.value
+
+
+def _taped_fwd(g, ref, w_scale, x_tape, d_tape, n_tape, rows, x, cfg, meta):
     # defvjp(..., symbolic_zeros=True) wraps every differentiable primal as
     # CustomVJPPrimal(value, perturbed); the tapes' values are never read.
-    del x_tape, d_tape
+    del x_tape, d_tape, n_tape
     g, ref, w_scale, x = g.value, ref.value, w_scale.value, x.value
-    y = _vmm_any(x, g, ref, w_scale, cfg, meta)
-    return y, (g, ref, w_scale, x)
+    rows = _value(rows)
+    y = _vmm_any(x, g, ref, w_scale, cfg, meta, rows)
+    return y, (g, ref, w_scale, rows, x)
 
 
 def _taped_bwd(cfg, meta, res, dy):
-    g, ref, w_scale, x = res
+    g, ref, w_scale, rows, x = res
     if isinstance(dy, SymbolicZero):  # y unused downstream: nothing flows
         dy = jnp.zeros(dy.aval.shape, dy.aval.dtype)
     dy32 = dy.astype(jnp.float32)
     # Error backprop: transpose read of the SAME (quantised, saturated,
-    # ADC'd) conductances the forward pass saw.
-    dx = _mvm_any(dy32, g, ref, w_scale, cfg, meta)
+    # ADC'd) conductances the forward pass saw.  Rows past an expert's
+    # count carry a zero error, as they carried a zero drive.
+    dx = _mvm_any(dy32, g, ref, w_scale, cfg, meta, rows)
     # The write drivers' operands, quantised exactly as the hardware does
     # (rows: temporal code, columns: voltage code).  They flow out through
     # the tape leaves; g/ref/w_scale get *symbolic* zero cotangents — the
     # dense (K, N) gradient is never formed, not even as a zeros fill.
     x_q, d_q = _quantize_operands_any(x.astype(jnp.float32), dy32, cfg)
+    if rows is None:
+        n_q = rows_ct = None
+    else:  # the counts ride to the write through the n_tape cotangent
+        n_q, rows_ct = rows.astype(jnp.float32), _symbolic_zero(rows)
     return (_symbolic_zero(g), _symbolic_zero(ref), _symbolic_zero(w_scale),
-            x_q, d_q, dx.astype(x.dtype))
+            x_q, d_q, n_q, rows_ct, dx.astype(x.dtype))
 
 
 _taped_matmul.defvjp(_taped_fwd, _taped_bwd, symbolic_zeros=True)
@@ -301,11 +317,12 @@ def analog_project(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
         d_tape = jnp.zeros((xb.shape[0], n), jnp.float32)
     # audit: allow RA103 -- ordered partial-sum/output combines of the shard-local read (shardctx.combine_partials_exact, anchored here by the custom_vjp call site): arithmetic-free activation-sized gathers in pinned order; RA107 bounds their compiled byte size
     y = _taped_matmul(effective_g(p, cfg), p["ref"], p["w_scale"], x_tape,
-                      d_tape, xb.astype(jnp.float32), cfg, meta)
+                      d_tape, None, None, xb.astype(jnp.float32), cfg, meta)
     return y.reshape(*lead, n).astype(x.dtype)
 
 
-def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
+def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig,
+                           rows: Optional[Array] = None) -> Array:
     """Apply an expert-batched container (g: (E, K, N)) to expert-batched
     activations x: (E, T, K) -> (E, T, N).
 
@@ -314,6 +331,10 @@ def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
     tape leaves ((E, T, K)/(E, T, N)) carry exactly the per-expert write
     operands and the stack updates as extra layers of the layer-batched
     rank-k write (``core.analog_registry.flatten_lead``).
+
+    ``rows`` (E,): each expert's routed rows, the first of its T (the
+    rest are zero).  The reads skip the rest, and the backward pass
+    leaves the counts in the container's ``n_tape`` slot for the write.
     """
     meta = p.get("tp_meta")
     e, k, n = meta.view(3) if meta is not None else p["g"].shape
@@ -326,10 +347,22 @@ def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
         x_tape = jnp.zeros(x.shape, jnp.float32)
     if d_tape is None:
         d_tape = jnp.zeros((e, x.shape[1], n), jnp.float32)
+    n_tape = p.get("n_tape")
+    if rows is not None:
+        rows = rows.astype(jnp.float32)
+        if n_tape is None:
+            n_tape = jnp.zeros((e,), jnp.float32)
+    else:
+        n_tape = None
     # audit: allow RA103 -- ordered EP-dispatch/partial-sum combines of the shard-local expert read (shardctx.combine_partials_exact, anchored here by the custom_vjp call site): arithmetic-free capacity-buffer gathers in pinned order; RA107 bounds their compiled byte size
     y = _taped_matmul(effective_g(p, cfg), p["ref"], p["w_scale"], x_tape,
-                      d_tape, x.astype(jnp.float32), cfg, meta)
+                      d_tape, n_tape, rows, x.astype(jnp.float32), cfg, meta)
     return y.astype(x.dtype)
+
+
+#: The in-step tape slots of a container: the write operands, and an
+#: expert-batched stack's routed row counts.
+TAPE_LEAVES = ("x_tape", "d_tape", "n_tape")
 
 
 def pop_tapes(params):
@@ -344,9 +377,8 @@ def pop_tapes(params):
     applied G times per step tapes G distinct operand blocks.
     """
     if is_analog_container(params):
-        tapes = {k: params[k] for k in ("x_tape", "d_tape") if k in params}
-        clean = {k: v for k, v in params.items()
-                 if k not in ("x_tape", "d_tape")}
+        tapes = {k: params[k] for k in TAPE_LEAVES if k in params}
+        clean = {k: v for k, v in params.items() if k not in TAPE_LEAVES}
         return clean, tapes, bool(tapes)
     if isinstance(params, dict):
         out = {k: pop_tapes(v) for k, v in params.items()}
@@ -366,7 +398,7 @@ def push_tapes(params, tapes):
     return params
 
 
-def make_tapes(p: dict, n_tokens) -> dict:
+def make_tapes(p: dict, n_tokens, counted: bool = False) -> dict:
     """Zero tape *slots* for one container (shapes (T, K) / (T, N)).
 
     Tape lifecycle: the train step allocates these slots (inside jit they
@@ -382,8 +414,10 @@ def make_tapes(p: dict, n_tokens) -> dict:
     container's own lead dims and the feature dim — ``(T,)`` for the
     ordinary once-per-step application, ``(reps, T)`` for a weight set
     applied ``reps`` times per step (the hybrid shared block), or the
-    per-expert ``(capacity,)`` of an expert-batched container (see
-    ``core.analog_registry.tape_lead``).
+    per-expert ``(T,)`` of an expert-batched container (see
+    ``core.analog_registry.tape_lead``).  ``counted`` adds the ``n_tape``
+    slot, one per matrix, for the routed row counts of an expert-batched
+    stack.
     """
     meta = p.get("tp_meta")
     # Tapes are replicated operand buffers: size them from the container's
@@ -392,17 +426,20 @@ def make_tapes(p: dict, n_tokens) -> dict:
     k, n = gshape[-2:]
     lead = gshape[:-2]  # scan-stacked containers carry (L, K, N)
     rows = n_tokens if isinstance(n_tokens, tuple) else (n_tokens,)
-    return {"x_tape": jnp.zeros((*lead, *rows, k), jnp.float32),
-            "d_tape": jnp.zeros((*lead, *rows, n), jnp.float32)}
+    tapes = {"x_tape": jnp.zeros((*lead, *rows, k), jnp.float32),
+             "d_tape": jnp.zeros((*lead, *rows, n), jnp.float32)}
+    if counted:
+        tapes["n_tape"] = jnp.zeros(lead, jnp.float32)
+    return tapes
 
 
 def with_tapes(params, n_tokens: int, tokens_for=None, path=()):
     """Recursively inject tape leaves next to every analog container.
 
     ``tokens_for(path, g_shape)`` optionally resolves the per-container
-    operand-row shape (expert capacity, shared-block reps); the default is
-    ``n_tokens`` rows everywhere, which is correct for trees whose every
-    container is applied once to the full token batch.
+    operand-row shape (shared-block reps); the default is ``n_tokens``
+    rows everywhere, which is correct for trees whose every container is
+    applied once to the full token batch.
 
     Prefer :func:`split_tapes` in training code — differentiating a
     ``with_tapes`` tree asks for cotangents of every g/ref/w_scale leaf,
@@ -418,7 +455,8 @@ def with_tapes(params, n_tokens: int, tokens_for=None, path=()):
     return params
 
 
-def split_tapes(params, n_tokens: int, tokens_for=None, path=()):
+def split_tapes(params, n_tokens: int, tokens_for=None, path=(),
+                counted=None):
     """Partition a parameter tree for the hoisted analog gradient.
 
     Returns ``(diff, frozen)``: ``diff`` carries every digital leaf plus,
@@ -432,18 +470,21 @@ def split_tapes(params, n_tokens: int, tokens_for=None, path=()):
 
     ``tokens_for``: per-container operand-row resolver, as in
     :func:`with_tapes` — the analog train step passes the registry's
-    family-aware resolver so MoE expert tapes are capacity-sized and the
-    hybrid shared block tapes one slot per group application.
+    family-aware resolver so MoE expert tapes hold the dropless buffer
+    and the hybrid shared block tapes one slot per group application.
+    ``counted(path)`` says which containers take an ``n_tape`` slot.
     """
     if is_analog_container(params):
         rows = tokens_for(path, params["g"].shape) if tokens_for \
             else n_tokens
-        return (make_tapes(params, rows),
+        return (make_tapes(params, rows,
+                           counted=bool(counted and counted(path))),
                 {k: params[k]
                  for k in ("g", "ref", "w_scale", "g_carry", "tp_meta")
                  if k in params})
     if isinstance(params, dict):
-        split = {k: split_tapes(v, n_tokens, tokens_for, path + (k,))
+        split = {k: split_tapes(v, n_tokens, tokens_for, path + (k,),
+                                counted)
                  for k, v in params.items()}
         return ({k: v[0] for k, v in split.items()},
                 {k: v[1] for k, v in split.items()})

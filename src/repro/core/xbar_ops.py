@@ -148,7 +148,7 @@ def _chain_read(x: Array, g: Array, g_ref: Array, w_scale: Array,
 
 def _read(x: Array, g: Array, g_ref: Array, w_scale: Array,
           cfg: CrossbarConfig, key: Optional[Array], impl: Optional[str],
-          transpose: bool, meta=None) -> Array:
+          transpose: bool, meta=None, rows=None) -> Array:
     g = _read_conductance(g, cfg, key)
     if meta is not None and meta.sharded:
         # Exact-mode manual-collective path: ``g``/``g_ref`` are this
@@ -164,12 +164,12 @@ def _read(x: Array, g: Array, g_ref: Array, w_scale: Array,
     from repro.kernels.xbar_vmm import xbar_fused_read_inline
     return replicate_for_exact_reduce(
         xbar_fused_read_inline(x, g, g_ref, w_scale, cfg,
-                               transpose=transpose, impl=impl))
+                               transpose=transpose, impl=impl, rows=rows))
 
 
 def vmm(x: Array, g: Array, g_ref: Array, w_scale: Array,
         cfg: CrossbarConfig, key: Optional[Array] = None,
-        impl: Optional[str] = None, meta=None) -> Array:
+        impl: Optional[str] = None, meta=None, rows=None) -> Array:
     """Analog vector-matrix multiply: y ≈ x @ W for W=(g-g_ref)/w_scale.
 
     ``x``: (..., B, K) float activations; ``g``/``g_ref``: (..., K, N)
@@ -177,17 +177,20 @@ def vmm(x: Array, g: Array, g_ref: Array, w_scale: Array,
     ``impl`` overrides ``cfg.read_impl`` (see the module docstring).
     ``meta`` (a ``shardctx.ShardMeta``) routes to the shard-local
     manual-collective read when the container is tile-sharded.
+    ``rows`` (lead dims): the rows of each matrix's B that are driven,
+    the first ones, the rest being zero; the kernel skips the rest (the
+    other paths read them, to the same result).
     """
     return _read(x, g, g_ref, w_scale, cfg, key, impl, transpose=False,
-                 meta=meta)
+                 meta=meta, rows=rows)
 
 
 def mvm(d: Array, g: Array, g_ref: Array, w_scale: Array,
         cfg: CrossbarConfig, key: Optional[Array] = None,
-        impl: Optional[str] = None, meta=None) -> Array:
+        impl: Optional[str] = None, meta=None, rows=None) -> Array:
     """Analog transpose read: y ≈ d @ W.T  (same array, columns driven)."""
     return _read(d, g, g_ref, w_scale, cfg, key, impl, transpose=True,
-                 meta=meta)
+                 meta=meta, rows=rows)
 
 
 def quantize_update_operands(

@@ -90,7 +90,7 @@ def model_projections(cfg: ModelConfig) -> List[Projection]:
         count = int(math.prod(shape[:-2])) if len(shape) > 2 else 1
         active = 1.0
         if kind == registry.EXPERT_BATCHED and cfg.n_experts:
-            active = cfg.top_k / cfg.n_experts
+            active = cfg.top_k / cfg.router_width
         else:
             active = float(registry.tape_reps(path, cfg))
         ps.append(Projection("/".join(path), int(k), int(n), count,

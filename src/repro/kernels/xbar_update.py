@@ -307,7 +307,17 @@ def _strip_rows(prow: int, cols: int) -> int:
 
 
 def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
-                   noise_mode: str, update_mode: str = "outer"):
+                   noise_mode: str, update_mode: str = "outer",
+                   counted: bool = False):
+    """One (layer, row tile, column tile, token block) grid step.
+
+    ``counted``: a scalar-prefetch (L,) operand, first, gives each
+    layer's filled tape rows (an expert's routed rows; the rest are
+    zero).  Token blocks past them add nothing to the outer product, so
+    they are skipped, and their tape blocks are not fetched (the index
+    map repeats the last filled block)."""
+    if counted:
+        rows_ref, *refs = refs
     if update_mode == "pulse_train":
         # Second output block: the |x| |d| magnitude accumulator rides the
         # same tile grid as the outer-product accumulator.
@@ -326,6 +336,9 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
     # interpreter can't lower.
     lid, kid, nid = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     m = scale_ref[lid]
+    if counted:
+        bb = x_ref.shape[1]
+        filled = bstep < (rows_ref[lid] + (bb - 1)) // bb
     if noise_mode == "kernel":
         # seed_ref is [base seed, layer/row/col tile offsets].  Offsets are
         # the shard's global base tile coordinates (zero when unsharded),
@@ -342,16 +355,22 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
         if a_ref is not None:
             a_ref[0, :, :] = jnp.zeros_like(a_ref[0, :, :])
 
-    # Accumulate the outer product sum_b x[b, :] d[b, :] for this tile.
-    o_ref[0, :, :] += jax.lax.dot_general(
-        x_ref[0, :, :], d_ref[0, :, :],
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32, precision=_HIGHEST)
-    if a_ref is not None:
-        a_ref[0, :, :] += jax.lax.dot_general(
-            jnp.abs(x_ref[0, :, :]), jnp.abs(d_ref[0, :, :]),
+    def accumulate():
+        # The outer product sum_b x[b, :] d[b, :] for this tile.
+        o_ref[0, :, :] += jax.lax.dot_general(
+            x_ref[0, :, :], d_ref[0, :, :],
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=_HIGHEST)
+        if a_ref is not None:
+            a_ref[0, :, :] += jax.lax.dot_general(
+                jnp.abs(x_ref[0, :, :]), jnp.abs(d_ref[0, :, :]),
+                dimension_numbers=(((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=_HIGHEST)
+
+    if counted:
+        pl.when(filled)(accumulate)
+    else:
+        accumulate()
 
     @pl.when(bstep == n_bsteps - 1)
     def _apply():
@@ -410,7 +429,7 @@ UPDATE_BLOCK_B = 256
 
 
 def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
-                   noise_mode, interpret, update_mode="outer"):
+                   noise_mode, interpret, update_mode="outer", rows=None):
     lyr, k, n = g.shape
     b = x_q.shape[1]
     bb = block_b or min(b, UPDATE_BLOCK_B)
@@ -420,13 +439,28 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
     _, kp, np_ = gp.shape
     bp = x_q.shape[1]
     grid = (lyr, kp // cfg.rows, np_ // cfg.cols, bp // bb)
+    counted = rows is not None
 
-    tile_spec = pl.BlockSpec((1, cfg.rows, cfg.cols),
-                             lambda l_, k_, n_, b_: (l_, k_, n_))
+    if counted:
+        # Index maps also take the scalar-prefetch row counts; a token
+        # block past an expert's rows maps to its last filled block, which
+        # is resident already, so nothing is fetched for it.
+        def last(r_, l_, b_):
+            return jnp.minimum(b_, jnp.maximum((r_[l_] + (bb - 1)) // bb - 1,
+                                               0))
+
+        tile_map = lambda l_, k_, n_, b_, r_: (l_, k_, n_)
+        x_map = lambda l_, k_, n_, b_, r_: (l_, last(r_, l_, b_), k_)
+        d_map = lambda l_, k_, n_, b_, r_: (l_, last(r_, l_, b_), n_)
+    else:
+        tile_map = lambda l_, k_, n_, b_: (l_, k_, n_)
+        x_map = lambda l_, k_, n_, b_: (l_, b_, k_)
+        d_map = lambda l_, k_, n_, b_: (l_, b_, n_)
+    tile_spec = pl.BlockSpec((1, cfg.rows, cfg.cols), tile_map)
     inputs = [x_q, d_q, gp]
     in_specs = [
-        pl.BlockSpec((1, bb, cfg.rows), lambda l_, k_, n_, b_: (l_, b_, k_)),
-        pl.BlockSpec((1, bb, cfg.cols), lambda l_, k_, n_, b_: (l_, b_, n_)),
+        pl.BlockSpec((1, bb, cfg.rows), x_map),
+        pl.BlockSpec((1, bb, cfg.cols), d_map),
         tile_spec,
     ]
     if noise_mode == "host":
@@ -450,18 +484,25 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
     else:
         out_specs = tile_spec
         out_shape = g_shape
+    kernel = functools.partial(_update_kernel, cfg=cfg, n_bsteps=grid[3],
+                               noise_mode=noise_mode,
+                               update_mode=update_mode, counted=counted)
+    if counted:
+        call = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+            out_specs=out_specs))
+        inputs = [rows.astype(jnp.int32)] + inputs
+    else:
+        call = dict(grid=grid, in_specs=in_specs, out_specs=out_specs)
     out = pl.pallas_call(
-        functools.partial(_update_kernel, cfg=cfg, n_bsteps=grid[3],
-                          noise_mode=noise_mode, update_mode=update_mode),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_specs,
+        kernel,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
             VMEM_LIMIT_CAP,
             _update_vmem_bytes(bb, cfg, noise_mode, update_mode))),
         interpret=interpret,
         name="xbar_update",
+        **call,
     )(*inputs)
     if update_mode == "pulse_train":
         out = out[0]
@@ -490,14 +531,15 @@ def _fused_update(g, x_q, d_q, scale, noise, seed, offs, cfg, noise_mode,
 
 
 def _dispatch_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
-                     impl, noise_mode, update_mode="outer"):
+                     impl, noise_mode, update_mode="outer", rows=None):
     if impl == "fused":
+        # The zero rows past a layer's count add nothing to the einsum.
         return _fused_update(g, x_q, d_q, scale, noise, seed, offs, cfg,
                              noise_mode, update_mode)
     return _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg,
                           block_b, noise_mode,
                           interpret=(impl == "interpret"),
-                          update_mode=update_mode)
+                          update_mode=update_mode, rows=rows)
 
 
 _outer_update = functools.partial(jax.jit, static_argnames=(
@@ -624,18 +666,24 @@ def xbar_outer_update_inline(g: Array, x_q: Array, d_q: Array, scale,
                              noise_mode: Optional[str] = None,
                              impl: Optional[str] = None,
                              tile_offsets=None,
-                             update_mode: Optional[str] = None) -> Array:
+                             update_mode: Optional[str] = None,
+                             rows: Optional[Array] = None) -> Array:
     """``xbar_outer_update`` without the jit wrapper, for callers already
     inside a jitted computation (the analog train step): the update inlines
     into the caller's graph, so per-container epilogues fuse with the rest
-    of the step instead of becoming separate pjit subcomputations."""
+    of the step instead of becoming separate pjit subcomputations.
+
+    ``rows`` (L,): each layer's filled tape rows, the first of its B (an
+    expert's routed rows); the kernel skips the zero rows past them."""
     in_dtype = g.dtype
     (g, x_q, d_q, scale, noise, seed, offs, noise_mode, impl,
      update_mode, squeeze) = _resolve_update_args(
          g, x_q, d_q, scale, cfg, noise, seed, noise_mode, impl, None,
          tile_offsets, update_mode)
+    if rows is not None:
+        rows = jnp.reshape(rows, (-1,))
     out = _dispatch_update(g, x_q, d_q, scale, noise, seed, offs, cfg,
-                           block_b, impl, noise_mode, update_mode)
+                           block_b, impl, noise_mode, update_mode, rows)
     if squeeze:
         out = out[0]
     return out.astype(in_dtype)

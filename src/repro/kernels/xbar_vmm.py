@@ -140,8 +140,9 @@ Array = jax.Array
 
 READ_IMPLS = ("auto", "pallas", "interpret", "jnp", "chain")
 # Scoped-VMEM ceiling for one kernel: v5e and v6e have 128 MiB of VMEM
-# per core; the rest stays with the compiler.
-VMEM_LIMIT_CAP = 100 * 2**20
+# per core; the rest stays with the compiler.  A 4096-token read block
+# at 1024x1024 tiles asks for 110 MiB.
+VMEM_LIMIT_CAP = 120 * 2**20
 # Every f32 contraction of the read and the write runs at full f32
 # precision on every backend: the TPU's default would round the
 # conductance operand to bfloat16.  The read kernel's three-pass path
@@ -212,9 +213,9 @@ def split_bf16x3(a: Array) -> tuple:
 READ_STRIP = 256
 
 
-def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
+def _read_kernel(x_ref, g_ref, r_ref, sc_ref, *refs,
                  cfg: CrossbarConfig, transpose: bool, n_steps: int,
-                 strip: int):
+                 strip: int, counted: bool):
     """One (matrix, batch block, output tile, reduction tile) grid step.
 
     VMM contracts the drive rows of the stored tile; MVM (``transpose``:
@@ -224,12 +225,28 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
     strips: the first integrates each strip's charge into the ``q_ref``
     scratch and accumulates the range statistics, the second converts it
     through the ADC into the resident output block.
+
+    ``counted``: an (L,) SMEM operand gives each matrix's driven rows,
+    the first of its block (an expert's routed rows; the rest are zero).
+    Both passes then stop at the last strip holding one, and the zero
+    rows keep the output block's initial zeros: a zero drive integrates
+    zero charge, which adds nothing to the range statistics and converts
+    to a zero code, so the result is the full block's, bit for bit.
     """
+    if counted:
+        n_ref, o_ref, q_ref = refs
+    else:
+        o_ref, q_ref = refs
     # Grid coordinates and the per-matrix scalars are read at the body's
     # top level: inside a pl.when branch they would land in a cond jaxpr
     # the interpreter cannot lower.
     lid, step = pl.program_id(0), pl.program_id(3)
     x_scale, out_scale = sc_ref[lid, 0], sc_ref[lid, 1]
+    n_strips = q_ref.shape[0] // strip
+    if counted:
+        n_active = jnp.minimum((n_ref[lid] + (strip - 1)) // strip, n_strips)
+    else:
+        n_active = n_strips
     n_rows = cfg.cols if transpose else cfg.rows
     contract = ((1,), (1,)) if transpose else ((1,), (0,))
     levels = float(cfg.adc.in_levels)
@@ -271,9 +288,8 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
         sumsq, nz = _charge_stats(q)
         return stats[0] + sumsq, stats[1] + nz
 
-    n_strips = q_ref.shape[0] // strip
     zero = jnp.zeros((), jnp.float32)
-    sumsq, nz = jax.lax.fori_loop(0, n_strips, integrate, (zero, zero))
+    sumsq, nz = jax.lax.fori_loop(0, n_active, integrate, (zero, zero))
     sat = integrator_range(sumsq, nz, cfg.adc, n_rows, cfg.device.gmax)
 
     def convert(i, carry):
@@ -281,7 +297,7 @@ def _read_kernel(x_ref, g_ref, r_ref, sc_ref, o_ref, q_ref, *,
         o_ref[0, ts, :] += _adc_epilogue(q_ref[ts, :], sat, cfg)
         return carry
 
-    jax.lax.fori_loop(0, n_strips, convert, 0)
+    jax.lax.fori_loop(0, n_active, convert, 0)
 
     @pl.when(step == n_steps - 1)
     def _rescale():
@@ -307,8 +323,11 @@ def _read_vmem_bytes(b: int, cfg: CrossbarConfig) -> int:
 
 def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
                  cfg: CrossbarConfig, transpose: bool,
-                 block_b: Optional[int], interpret: bool) -> Array:
-    """Launch the fused kernel over lead-flattened (L, ...) operands."""
+                 block_b: Optional[int], interpret: bool,
+                 rows: Optional[Array] = None) -> Array:
+    """Launch the fused kernel over lead-flattened (L, ...) operands;
+    ``rows`` (L,) int32 are the driven rows of each matrix (see
+    :func:`_read_kernel`), one batch block only."""
     lyr, b = x.shape[0], x.shape[1]
     k, n = g.shape[1], g.shape[2]
     bb = block_b or b
@@ -346,16 +365,20 @@ def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
         g_index = lambda l_, b_, o_, r_: (l_, r_, o_)
         out_shape, out_dim = (lyr, bp, np_), n
     g_spec = pl.BlockSpec((1, cfg.rows, cfg.cols), g_index)
+    counted = rows is not None
+    if counted and bp != bb:
+        raise ValueError("a read with row counts takes one batch block")
+    # The (L, 2) per-matrix scalars (and the (L,) row counts) sit whole in
+    # SMEM, indexed by the lead grid coordinate inside the body.
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    operands = (x, gp, rp, sc) + ((rows,) if counted else ())
     out = pl.pallas_call(
         functools.partial(_read_kernel, cfg=cfg, transpose=transpose,
-                          n_steps=grid[3], strip=strip),
+                          n_steps=grid[3], strip=strip, counted=counted),
         grid=grid,
-        # The (L, 2) per-matrix scalars sit whole in SMEM, indexed by the
-        # lead grid coordinate inside the body.
         in_specs=[pl.BlockSpec((1, bb, drive),
                                lambda l_, b_, o_, r_: (l_, b_, r_)),
-                  g_spec, g_spec,
-                  pl.BlockSpec(memory_space=pltpu.SMEM)],
+                  g_spec, g_spec, smem] + ([smem] if counted else []),
         out_specs=pl.BlockSpec((1, bb, out_w),
                                lambda l_, b_, o_, r_: (l_, b_, o_)),
         out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
@@ -363,7 +386,7 @@ def _pallas_read(x: Array, g: Array, ref: Array, sc: Array,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
         name="xbar_vmm",
-    )(x, gp, rp, sc)
+    )(*operands)
     return out[:, :b, :out_dim]
 
 
@@ -504,7 +527,8 @@ def _read_one_jnp(x: Array, g: Array, ref: Array, w_scale: Array,
 def xbar_fused_read_inline(x: Array, g: Array, ref: Array, w_scale,
                            cfg: CrossbarConfig, *, transpose: bool = False,
                            block_b: Optional[int] = None,
-                           impl: Optional[str] = None) -> Array:
+                           impl: Optional[str] = None,
+                           rows: Optional[Array] = None) -> Array:
     """The fused read, inlined into the caller's trace (no jit wrapper).
 
     ``x``: (..., B, K) float activations ((..., B, N) when ``transpose``);
@@ -521,6 +545,9 @@ def xbar_fused_read_inline(x: Array, g: Array, ref: Array, w_scale,
     its own DAC full scale), matching the vmapped per-expert reference.
     ``block_b`` batches the kernel grid over B; dynamic ADC range matches
     the reference only when one block covers the whole batch (default).
+    ``rows`` (lead dims) counts each matrix's driven rows, the first of
+    its B, the rest being zero: the kernel walks only the strips that
+    hold them, to the same result (the jnp twin reads every row).
     """
     impl = resolve_read_impl(impl)
     if impl == "chain":
@@ -554,8 +581,10 @@ def xbar_fused_read_inline(x: Array, g: Array, ref: Array, w_scale,
     x_scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=(1, 2)),
                           1e-12) / cfg.adc.in_levels
     sc = jnp.stack([x_scale, x_scale / w_scale.reshape(lyr)], axis=1)
+    if rows is not None:
+        rows = jnp.broadcast_to(rows, lead).reshape(lyr).astype(jnp.int32)
     y = _pallas_read(xf, gf, rf, sc, cfg, transpose, block_b,
-                     interpret=(impl == "interpret"))
+                     interpret=(impl == "interpret"), rows=rows)
     y = y.reshape(*lead, *y.shape[1:]) if lead else y[0]
     return y.astype(in_dtype)
 

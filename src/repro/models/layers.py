@@ -11,6 +11,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import math
 import os
 from typing import Optional, Tuple
 
@@ -166,8 +167,13 @@ def project(p: dict, x: Array, cfg: ModelConfig) -> Array:
     return y.astype(x.dtype)
 
 
-def expert_project(p, x: Array, cfg: ModelConfig) -> Array:
+def expert_project(p, x: Array, cfg: ModelConfig,
+                   rows: Optional[Array] = None) -> Array:
     """Expert-batched linear layer: x (E, T, K) -> (E, T, N).
+
+    ``rows`` (E,) optionally gives each expert's filled rows, the first of
+    its T (the rest are zero): the crossbar kernels then read and write
+    only those.
 
     ``p`` is either a raw (E, K, N) weight stack (digital / fakequant MoE)
     or an expert-batched tiled-crossbar container (device mode) — each
@@ -181,7 +187,8 @@ def expert_project(p, x: Array, cfg: ModelConfig) -> Array:
     the MoE expert einsums, not just the dense projections.
     """
     if is_analog_container(p):
-        return analog_project_batched(p, x, crossbar_from_model(cfg))
+        return analog_project_batched(p, x, crossbar_from_model(cfg),
+                                      rows=rows)
     if resolve_analog_mode(cfg) is AnalogMode.DIGITAL:
         return jnp.einsum("etk,ekn->etn", x, p.astype(x.dtype))
     adc = AdcConfig(in_bits=cfg.analog_in_bits,
@@ -211,14 +218,55 @@ def rope_freqs(dim: int, theta: float) -> Array:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def apply_rope(x: Array, positions: Array, theta: float) -> Array:
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature factor (DeepSeek-V2 ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, cfg: ModelConfig) -> Array:
+    """YaRN inverse frequencies (DeepSeek-V2's rotary embedding): the
+    interpolated ``1 / (factor * theta**(2i/dim))`` and the extrapolated
+    ``1 / theta**(2i/dim)``, blended by a linear ramp over the dims
+    between the ``beta_fast`` and ``beta_slow`` rotation counts of the
+    original context (extrapolated below the ramp, interpolated above)."""
+    base, factor = cfg.rope_theta, cfg.yarn_factor
+    ctx = cfg.yarn_original_max_pos
+
+    def dim_of(rotations):
+        return dim * math.log(ctx / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(dim_of(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(dim_of(cfg.yarn_beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(dim, base)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_table(dim: int, cfg: ModelConfig) -> Tuple[Array, float]:
+    """(inverse frequencies, cos/sin magnitude) of the config's rope."""
+    if cfg.yarn_factor > 1.0:
+        mag = yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) \
+            / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim)
+        return yarn_freqs(dim, cfg), mag
+    return rope_freqs(dim, cfg.rope_theta), 1.0
+
+
+def apply_rope(x: Array, positions: Array, theta: float,
+               freqs: Optional[Array] = None, mag: float = 1.0) -> Array:
+    """x: (..., seq, heads, head_dim); positions: (..., seq).  ``freqs``
+    replaces the plain table of ``theta`` (YaRN, :func:`rope_table`) and
+    ``mag`` scales cos and sin."""
     dt = x.dtype
     dim = x.shape[-1]
-    freqs = rope_freqs(dim, theta)
+    if freqs is None:
+        freqs = rope_freqs(dim, theta)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (.., s, d/2)
-    cos = jnp.cos(angles)[..., :, None, :]
-    sin = jnp.sin(angles)[..., :, None, :]
+    cos = mag * jnp.cos(angles)[..., :, None, :]
+    sin = mag * jnp.sin(angles)[..., :, None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin,
                             x2 * cos + x1 * sin], axis=-1).astype(dt)
@@ -266,7 +314,8 @@ def _split_heads(x: Array, n: int) -> Array:
 
 
 def _chunked_sdpa(q: Array, k: Array, v: Array, causal: bool,
-                  q_offset: int = 0) -> Array:
+                  q_offset: int = 0, scale: Optional[float] = None
+                  ) -> Array:
     """Softmax attention, scanning over query chunks.
 
     q: (B, Sq, H, hd);  k/v: (B, Skv, KVH, hd).  GQA folds the head group
@@ -275,7 +324,7 @@ def _chunked_sdpa(q: Array, k: Array, v: Array, causal: bool,
     b, sq, h, hd = q.shape
     skv, kvh = k.shape[1], k.shape[2]
     group = h // kvh
-    scale = 1.0 / np.sqrt(hd)
+    scale = scale or 1.0 / np.sqrt(hd)
     qg = q.reshape(b, sq, kvh, group, hd)
 
     n_chunks = max(1, sq // Q_CHUNK) if sq % Q_CHUNK == 0 else 1
@@ -303,7 +352,8 @@ def _chunked_sdpa(q: Array, k: Array, v: Array, causal: bool,
     return out.astype(q.dtype)
 
 
-def _decode_sdpa(q: Array, k: Array, v: Array, kv_len: Array) -> Array:
+def _decode_sdpa(q: Array, k: Array, v: Array, kv_len: Array,
+                 scale: Optional[float] = None) -> Array:
     """Single-token attention against a (possibly sequence-sharded) cache.
 
     q: (B, 1, H, hd); k/v: (B, S, KVH, hd).  The cache sequence is viewed as
@@ -313,7 +363,7 @@ def _decode_sdpa(q: Array, k: Array, v: Array, kv_len: Array) -> Array:
     b, _, h, hd = q.shape
     s, kvh = k.shape[1], k.shape[2]
     group = h // kvh
-    scale = 1.0 / np.sqrt(hd)
+    scale = scale or 1.0 / np.sqrt(hd)
     c = DECODE_KV_CHUNKS if s % DECODE_KV_CHUNKS == 0 else 1
     sl = s // c
     kc = k.reshape(b, c, sl, kvh, hd)
@@ -336,7 +386,8 @@ def _decode_sdpa(q: Array, k: Array, v: Array, kv_len: Array) -> Array:
     return o.reshape(b, 1, h, v.shape[-1]).astype(q.dtype)
 
 
-def _cached_sdpa(q: Array, k: Array, v: Array, q_pos: Array) -> Array:
+def _cached_sdpa(q: Array, k: Array, v: Array, q_pos: Array,
+                 scale: Optional[float] = None) -> Array:
     """Chunk attention against a partially-filled cache (chunked prefill).
 
     q: (B, Sq, H, hd); k/v: (B, S, KVH, hd) — the full cache after this
@@ -348,7 +399,7 @@ def _cached_sdpa(q: Array, k: Array, v: Array, q_pos: Array) -> Array:
     b, sq, h, hd = q.shape
     s, kvh = k.shape[1], k.shape[2]
     group = h // kvh
-    scale = 1.0 / np.sqrt(hd)
+    scale = scale or 1.0 / np.sqrt(hd)
     qg = q.reshape(b, sq, kvh, group, hd)
     scores = jnp.einsum("bqkgd,bskd->bqkgs", qg.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
@@ -491,22 +542,34 @@ def mla_attention(p: dict, x: Array, cfg: ModelConfig, *,
     """Multi-head latent attention.  The cache stores the compressed
     latent (kv_lora_rank) + shared rope key — MLA's memory saving.
     Append mode (decode, or chunked prefill when ``positions`` is given)
-    writes at the cached ``len``; see ``attention``."""
+    writes at the cached ``len``; see ``attention``.  With YaRN rope the
+    softmax scale carries the squared temperature factor ``mscale``
+    (DeepSeek-V2: ``mscale(factor, mscale_all_dim)**2 / sqrt(qk_dim)``)."""
+    with jax.named_scope("attention"):
+        return _mla_attention(p, x, cfg, positions, cache)
+
+
+def _mla_attention(p: dict, x: Array, cfg: ModelConfig, positions,
+                   cache) -> Tuple[Array, Optional[dict]]:
     b, sq, d = x.shape
     h = cfg.n_heads
     qk_dim = cfg.qk_nope_dim + cfg.qk_rope_dim
     append = cache is not None and (sq == 1 or positions is not None)
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(sq), (b, sq))
+    freqs, mag = rope_table(cfg.qk_rope_dim, cfg)
+    scale = 1.0 / np.sqrt(qk_dim)
+    if cfg.yarn_factor > 1.0 and cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
     q = _split_heads(project(p["wq"], x, cfg), h)  # (b,s,h,qk_dim)
     q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, freqs, mag)
 
     kv_a = project(p["wkv_a"], x, cfg)
     c_kv, k_rope = jnp.split(kv_a, [cfg.kv_lora_rank], axis=-1)
     c_kv = rmsnorm(p["kv_norm"], c_kv, cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions,
-                        cfg.rope_theta)  # single shared rope head
+    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta,
+                        freqs, mag)  # single shared rope head
 
     if append:
         idx = cache["len"]
@@ -544,7 +607,6 @@ def mla_attention(p: dict, x: Array, cfg: ModelConfig, *,
         dn, dv = cfg.qk_nope_dim, cfg.v_head_dim
         wkv = p["wkv_b"]["w"].astype(jnp.float32).reshape(r, h, dn + dv)
         wkb, wvb = wkv[..., :dn], wkv[..., dn:]
-        scale = 1.0 / np.sqrt(dn + cfg.qk_rope_dim)
         q_abs = jnp.einsum("bhd,rhd->bhr",
                            q_nope[:, 0].astype(jnp.float32), wkb)
         c32 = c_all.astype(jnp.float32)
@@ -570,11 +632,11 @@ def mla_attention(p: dict, x: Array, cfg: ModelConfig, *,
     q_full = jnp.concatenate([q_nope, q_rope], axis=-1)
 
     if append and sq == 1:
-        o = _decode_sdpa(q_full, k_full, v, kv_len)
+        o = _decode_sdpa(q_full, k_full, v, kv_len, scale=scale)
     elif append:
-        o = _cached_sdpa(q_full, k_full, v, positions)
+        o = _cached_sdpa(q_full, k_full, v, positions, scale=scale)
     else:
-        o = _chunked_sdpa(q_full, k_full, v, causal=True)
+        o = _chunked_sdpa(q_full, k_full, v, causal=True, scale=scale)
     out = project(p["wo"], o.reshape(b, sq, -1), cfg)
     return out, new_cache
 

@@ -100,8 +100,11 @@ def program_digital(params, cfg: ModelConfig, path=()):
 
 
 def forward(params: dict, batch: Dict[str, Array], cfg: ModelConfig,
-            caches=None, shared_caches=None, positions=None):
-    """Returns (logits, new_caches, new_shared_caches, aux)."""
+            caches=None, shared_caches=None, positions=None,
+            stats: bool = False):
+    """Returns (logits, new_caches, new_shared_caches, aux).  ``aux`` is
+    the scalar auxiliary loss, or with ``stats`` an MoE stack's routing
+    stats (``moe.moe_layer``) where the model has them."""
     tokens = batch["tokens"]
     if cfg.family == "vlm":
         logits, nc, aux = tf.vlm_apply(params, tokens, batch["vision"],
@@ -125,12 +128,14 @@ def forward(params: dict, batch: Dict[str, Array], cfg: ModelConfig,
         return logits, ns, nsh, aux
     logits, nc, aux = tf.decoder_apply(params, tokens, cfg, caches=caches,
                                        positions=positions)
+    if isinstance(aux, dict) and not stats:
+        aux = aux["aux"]
     return logits, nc, None, aux
 
 
 def loss_fn(params: dict, batch: Dict[str, Array], cfg: ModelConfig
             ) -> Tuple[Array, Dict[str, Array]]:
-    logits, _, _, aux = forward(params, batch, cfg)
+    logits, _, _, aux = forward(params, batch, cfg, stats=True)
     labels = batch["labels"]
     # Sharding-friendly CE: one-hot contraction instead of take_along_axis
     # (a gather over the vocab-sharded dim would force an all-gather of the
@@ -142,8 +147,12 @@ def loss_fn(params: dict, batch: Dict[str, Array], cfg: ModelConfig
             logits * jax.nn.one_hot(labels, cfg.vocab, dtype=jnp.float32),
             axis=-1)
         loss = jnp.mean(lse - true_logit)
+    # An MoE stack's aux is its routing stats: the balance loss and the
+    # dispatch counters (``moe.moe_apply``), which ride out as metrics.
+    stats = dict(aux) if isinstance(aux, dict) else {"aux": aux}
+    aux = stats.pop("aux")
     total = loss + 0.01 * aux
-    return total, {"ce": loss, "aux": aux}
+    return total, {"ce": loss, "aux": aux, **stats}
 
 
 # --------------------------------------------------------------------------

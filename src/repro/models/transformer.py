@@ -59,16 +59,27 @@ def _remat(f):
 # Blocks
 # --------------------------------------------------------------------------
 
+def _self_attn_init(key: Array, cfg: ModelConfig) -> dict:
+    return mla_init(key, cfg) if cfg.use_mla else attn_init(key, cfg)
+
+
+def _self_attn(p: dict, x: Array, cfg: ModelConfig, positions, cache):
+    if cfg.use_mla:
+        return mla_attention(p, x, cfg, positions=positions, cache=cache)
+    return attention(p, x, cfg, positions=positions, cache=cache)
+
+
 def dense_block_init(key: Array, cfg: ModelConfig) -> dict:
     k1, k2 = jax.random.split(key)
-    return {"ln1": rmsnorm_init(cfg.d_model), "attn": attn_init(k1, cfg),
+    return {"ln1": rmsnorm_init(cfg.d_model),
+            "attn": _self_attn_init(k1, cfg),
             "ln2": rmsnorm_init(cfg.d_model), "ffn": ffn_init(k2, cfg)}
 
 
 def dense_block(p: dict, x: Array, cfg: ModelConfig, positions, cache):
     x = shard_batch_dim(x)
-    h, new_cache = attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
-                             cfg, positions=positions, cache=cache)
+    h, new_cache = _self_attn(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                              cfg, positions, cache)
     x = x + h
     x = x + ffn(p["ffn"], rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
     return x, new_cache, jnp.zeros((), jnp.float32)
@@ -76,24 +87,21 @@ def dense_block(p: dict, x: Array, cfg: ModelConfig, positions, cache):
 
 def moe_block_init(key: Array, cfg: ModelConfig) -> dict:
     k1, k2 = jax.random.split(key)
-    attn = mla_init(k1, cfg) if cfg.use_mla else attn_init(k1, cfg)
-    return {"ln1": rmsnorm_init(cfg.d_model), "attn": attn,
+    return {"ln1": rmsnorm_init(cfg.d_model),
+            "attn": _self_attn_init(k1, cfg),
             "ln2": rmsnorm_init(cfg.d_model), "moe": moe_mod.moe_init(k2, cfg)}
 
 
 def moe_block(p: dict, x: Array, cfg: ModelConfig, positions, cache):
+    """Returns (x, cache, routing stats): the aux loss and the dispatch
+    counters of ``moe.moe_layer``."""
     x = shard_batch_dim(x)
-    xn = rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if cfg.use_mla:
-        h, new_cache = mla_attention(p["attn"], xn, cfg,
-                                     positions=positions, cache=cache)
-    else:
-        h, new_cache = attention(p["attn"], xn, cfg, positions=positions,
-                                 cache=cache)
+    h, new_cache = _self_attn(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                              cfg, positions, cache)
     x = x + h
-    y, aux = moe_mod.moe_apply(p["moe"], rmsnorm(p["ln2"], x, cfg.norm_eps),
-                               cfg)
-    return x + y, new_cache, aux
+    y, stats = moe_mod.moe_layer(p["moe"],
+                                 rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
+    return x + y, new_cache, stats
 
 
 def cross_block_init(key: Array, cfg: ModelConfig) -> dict:
@@ -139,17 +147,28 @@ def _stack_init(key: Array, n: int, init_fn) -> dict:
     return jax.vmap(init_fn)(jax.random.split(key, n))
 
 
-def _scan_blocks(params, x, body, caches=None, length=None):
+def _scan_blocks(params, x, body, caches=None, length=None, aux0=None):
     """Scan ``body`` over stacked layer params (+ optional stacked caches).
 
-    body(layer_params, x, cache) -> (x, new_cache, aux)
+    body(layer_params, x, cache) -> (x, new_cache, aux).  ``aux`` is a
+    scalar summed over the layers, or a dict shaped like ``aux0`` whose
+    ``max_*`` entries take the layers' maximum and the rest their sum.
     """
+    if aux0 is None:
+        aux0 = jnp.zeros((), jnp.float32)
+
+    def combine(acc, aux):
+        if not isinstance(acc, dict):
+            return acc + aux
+        return {k: jnp.maximum(v, aux[k]) if k.startswith("max_")
+                else v + aux[k] for k, v in acc.items()}
+
     def f(carry, xs):
         lp, cache = xs
-        x, aux_sum = carry
+        x, aux_acc = carry
         with jax.named_scope("layer"):
             x, new_cache, aux = body(lp, x, cache)
-            return (x, aux_sum + aux), new_cache
+            return (x, combine(aux_acc, aux)), new_cache
 
     # The scan's own work (slicing and stacking the per-layer trees) is
     # ``layer_scan``; what a layer computes is ``layer`` or a scope
@@ -157,8 +176,7 @@ def _scan_blocks(params, x, body, caches=None, length=None):
     xs = (params, caches)
     with jax.named_scope("layer_scan"):
         (x, aux), new_caches = jax.lax.scan(
-            _remat(f), (x, jnp.zeros((), jnp.float32)), xs,
-            length=length)
+            _remat(f), (x, aux0), xs, length=length)
     return x, new_caches, aux
 
 
@@ -167,14 +185,20 @@ def _scan_blocks(params, x, body, caches=None, length=None):
 # --------------------------------------------------------------------------
 
 def decoder_init(key: Array, cfg: ModelConfig) -> dict:
+    """Embedding, the stacked layers and the head.  An MoE model's
+    ``n_dense_layers`` leading dense layers are a stack of their own,
+    ``dense_layers``, scanned before the expert layers of ``layers``."""
     ks = jax.random.split(key, 4)
     block_init = moe_block_init if cfg.n_experts else dense_block_init
     p = {
         "embed": embed_init(ks[0], cfg.vocab, cfg.d_model),
-        "layers": _stack_init(ks[1], cfg.n_layers,
+        "layers": _stack_init(ks[1], cfg.n_layers - cfg.n_dense_layers,
                               partial(block_init, cfg=cfg)),
         "final_ln": rmsnorm_init(cfg.d_model),
     }
+    if cfg.n_dense_layers:
+        p["dense_layers"] = _stack_init(ks[3], cfg.n_dense_layers,
+                                        partial(dense_block_init, cfg=cfg))
     if not cfg.tie_embeddings:
         p["lm_head"] = {"w": dense_init(ks[2], cfg.d_model, cfg.vocab)}
     return p
@@ -202,11 +226,29 @@ def _embed_lookup(p: dict, tokens: Array, cfg: ModelConfig) -> Array:
 def decoder_apply(p: dict, tokens: Array, cfg: ModelConfig, *,
                   caches=None, positions=None
                   ) -> Tuple[Array, Any, Array]:
+    """Logits, new caches and the aux of the layer stack (for an MoE
+    model the routing stats of ``moe.moe_layer``, combined over layers)."""
     x = _embed_lookup(p, tokens, cfg)
-    block = moe_block if cfg.n_experts else dense_block
+    n_dense = cfg.n_dense_layers
+    dense_caches = None
+    if n_dense:
+        if caches is not None:
+            dense_caches = jax.tree.map(lambda a: a[:n_dense], caches)
+            caches = jax.tree.map(lambda a: a[n_dense:], caches)
+        body = lambda lp, h, c: dense_block(lp, h, cfg, positions, c)
+        x, dense_caches, _ = _scan_blocks(p["dense_layers"], x, body,
+                                          dense_caches, length=n_dense)
+    if cfg.n_experts:
+        block, aux0 = moe_block, moe_mod.stats_zero()
+    else:
+        block, aux0 = dense_block, None
     body = lambda lp, h, c: block(lp, h, cfg, positions, c)
     x, new_caches, aux = _scan_blocks(p["layers"], x, body, caches,
-                                     length=cfg.n_layers)
+                                     length=cfg.n_layers - n_dense,
+                                     aux0=aux0)
+    if dense_caches is not None and new_caches is not None:
+        new_caches = jax.tree.map(lambda a, b: jnp.concatenate([a, b]),
+                                  dense_caches, new_caches)
     return _logits(p, x, cfg), new_caches, aux
 
 

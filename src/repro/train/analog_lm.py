@@ -27,10 +27,11 @@ The mapping from parameter path to container / tape route / update view
 is the family-agnostic registry (``core/analog_registry.py``): MoE
 expert stacks are expert-batched (L, E, K, N) containers whose expert
 dim flattens onto the kernel's layer grid (one ``pallas_call`` per
-container, capacity-sized per-expert tapes), SSD in/out projections are
-ordinary scan-stacked containers, the hybrid shared block tapes one
-operand slot per group application, and the fused cross-attention array
-is driven by both token streams in one application.  The first call
+container; dropless per-expert tapes, the routed row counts beside
+them), SSD in/out projections are ordinary scan-stacked containers, the
+hybrid shared block tapes one operand slot per group application, and
+the fused cross-attention array is driven by both token streams in one
+application.  The first call
 audits the tree — an unmapped projection-family matrix raises instead
 of silently training digitally.
 
@@ -50,7 +51,7 @@ The whole step body runs under ``shard_map``: the read is shard-local
 per-tile ADC partial sums — ``kernels/xbar_vmm.manual_collective_read``;
 conductances never cross a shard boundary), the expert dim of an MoE
 container is an EP dispatch (each shard reads only its own experts'
-rows of the replicated capacity buffer and the combine gathers the
+rows of the replicated dispatch buffer and the combine gathers the
 small output buffers), and the rank-k write updates only the local tile
 block with shard-invariant counter-PRNG seeds.  Activations stay
 replicated, and every cross-shard exchange is an arithmetic-free gather
@@ -62,6 +63,7 @@ Use :meth:`shard_state` to lay an initial state out on the mesh.
 """
 from __future__ import annotations
 
+import contextlib
 import zlib
 from typing import Dict, Optional, Tuple
 
@@ -324,13 +326,16 @@ class AnalogTrainStep:
 
         # Hoist g/ref/w_scale out of the differentiated arguments: the grads
         # tree holds exactly the tape cotangents + digital gradients.  The
-        # registry resolves each container's tape route: capacity-sized
-        # slots per expert, one slot block per application for the hybrid
-        # shared weights, n_tokens rows everywhere else.
+        # registry resolves each container's tape route: a dropless slot
+        # per token and expert (with the routed row counts beside them),
+        # one slot block per application for the hybrid shared weights,
+        # n_tokens rows everywhere else.
         diff, frozen = split_tapes(
             read_params, n_tokens,
             tokens_for=lambda path, shape: registry.tape_lead(
-                path, cfg, n_tokens, batch["tokens"].shape))
+                path, cfg, n_tokens, batch["tokens"].shape),
+            counted=lambda path: registry.classify(path)
+            == registry.EXPERT_BATCHED)
         (loss, metrics), grads = jax.value_and_grad(
             lambda d: M.loss_fn(merge_tapes(d, frozen), batch, cfg),
             has_aux=True)(diff)
@@ -420,7 +425,12 @@ class AnalogTrainStep:
 
     def _update(self, p, g, key, seed_base, path, rail):
         if is_analog_container(p):
-            with jax.named_scope("xbar.write"):
+            # An expert stack's write is named with its reads
+            # (``moe.experts``, models/moe).
+            expert = registry.classify(path) == registry.EXPERT_BATCHED
+            outer = jax.named_scope("moe.experts") if expert \
+                else contextlib.nullcontext()
+            with outer, jax.named_scope("xbar.write"):
                 return self._update_container(p, g, key, seed_base, path,
                                               rail)
         if isinstance(p, dict):
@@ -439,9 +449,20 @@ class AnalogTrainStep:
         the kernel's layer axis, so the write stays ONE ``pallas_call``
         per container for every family.  On a mesh each shard writes only
         the tiles it owns (tape slices local, PRNG counters globally
-        indexed)."""
+        indexed).
+
+        An expert-batched stack's tapes carry each expert's routed row
+        counts (``n_tape``), which the kernel uses to skip the rest; its
+        experts draw the write noise of experts ``first_expert ..`` of
+        the whole layer: the flattened layer index is offset by
+        ``first_expert`` experts' worth of rows."""
         smap = self.mesh is not None and self.exact
         kind = registry.classify(path)
+        rows = tapes.get("n_tape")
+        held_off = 0
+        if kind == registry.EXPERT_BATCHED and p["g"].ndim > 2:
+            held_off = self.cfg.first_expert \
+                * int(np.prod(p["g"].shape[:-3]))
         noise = seed = None
         mode = "none"
         if seed_base is not None:
@@ -468,7 +489,8 @@ class AnalogTrainStep:
             scale = scale * jnp.float32(self.xcfg.carry_base)
         if smap:
             g_new, railed, total = self._local_block_update(
-                p[leaf], tapes, scale, noise, seed, mode, path, kind)
+                p[leaf], tapes, scale, noise, seed, mode, path, kind,
+                held_off)
             rail.append(railed / total)
         else:
             g3, x3, d3, s1, n3, unflatten = registry.flatten_lead(
@@ -482,7 +504,10 @@ class AnalogTrainStep:
             else:
                 g3_new = xbar_outer_update_inline(
                     g3, x3, d3, s1, self.xcfg, noise=n3, seed=seed,
-                    noise_mode=mode, impl=self.impl)
+                    noise_mode=mode, impl=self.impl,
+                    tile_offsets=(held_off, 0, 0),
+                    rows=None if rows is None
+                    else registry.flatten_counts(kind, rows))
             g_new = unflatten(g3_new)
             dev = self.xcfg.device
             span = dev.gmax - dev.gmin
@@ -545,7 +570,7 @@ class AnalogTrainStep:
         }
 
     def _local_block_update(self, g_arr, tapes, scale, noise, seed, mode,
-                            path, kind):
+                            path, kind, held_off=0):
         """Rank-k write of one shard's tile block (inside shard_map):
         slice the (replicated) tapes and noise to the block this shard
         owns — including its expert range for expert-batched containers —
@@ -584,7 +609,7 @@ class AnalogTrainStep:
         # counter PRNG by the range's global base.  The registry hoists
         # the (single) sharded lead dim outermost, so the offset is one
         # scalar: base_expert * (flattened rows per expert).
-        lead_off = jnp.uint32(0)
+        lead_off = jnp.uint32(held_off)
         for d in range(lead):
             names_d = _spec_names(g_spec[d])
             if not names_d:

@@ -4,7 +4,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
-from repro.models.moe import moe_apply, moe_dense_reference, moe_init
+from repro.models.moe import (moe_apply, moe_dense_reference, moe_init,
+                              moe_layer)
 
 
 def _setup(name="llama4-scout-17b-a16e", capacity=64.0, **over):
@@ -33,10 +34,19 @@ def test_dispatch_matches_dense_reference_topk2():
 
 
 def test_capacity_drops_tokens_gracefully():
-    """With capacity 0+, outputs fall back to the shared path only."""
+    """The digital path (the sharded dry runs' caller) still drops past
+    ``capacity_factor`` times the mean load (device mode is dropless,
+    tests/test_moe_dropless.py): at the smallest capacity every held
+    expert keeps 8 rows, the drops are counted, and the output falls back
+    to the shared path plus what was kept."""
     cfg, p, x = _setup(capacity=1e-6)
-    y, aux = moe_apply(p, x, cfg)
+    y, stats = moe_layer(p, x, cfg)
     assert bool(jnp.all(jnp.isfinite(y)))
+    t = x.shape[0] * x.shape[1]
+    assert int(stats["max_expert_rows"]) == 8
+    assert int(stats["moe_dropped"]) > 0
+    assert int(stats["moe_routed_rows"]) + int(stats["moe_dropped"]) \
+        == t * cfg.top_k
     # shared expert only
     from repro.models.layers import ffn
     y_shared = ffn(p["shared"], x.reshape(-1, cfg.d_model),

@@ -292,7 +292,7 @@ _MOE_EP_SCRIPT = """
     bad = [jtu.keystr(p) for p, v in jtu.tree_flatten_with_path(same)[0]
            if not v]
     assert not bad, bad
-    g = states["local"]["params"]["layers"]["moe"]["experts"]["w_up"]["g"]
+    g = states["local"]["params"]["layers"]["moe"]["experts"]["w_upgate"]["g"]
     assert not g.sharding.is_fully_replicated, g.sharding
     print("EP_PARITY_OK")
 """
@@ -315,7 +315,7 @@ def test_sharded_step_bit_identical_moe_2x4():
     shard), expert row tiles over ``data`` — produces bit-identical
     conductances to 1 device, probed on an expert container."""
     r = _run(_parity("llama4-scout-17b-a16e", (2, 4), 16,
-                     '["layers"]["moe"]["experts"]["w_up"]'))
+                     '["layers"]["moe"]["experts"]["w_upgate"]'))
     assert "PARITY_OK" in r.stdout, r.stdout + r.stderr
 
 

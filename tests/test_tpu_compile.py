@@ -193,3 +193,60 @@ def test_lowered_step_names_its_layers():
     for k in KERNEL_NAMES:
         assert any(f"{k}/pallas_call" in n for n in stacks), k
     assert set(SCOPES) <= _stack_names(stacks)
+
+
+# DeepSeek-V2-Lite on one chip: the dense layer and 4 expert layers at
+# published widths, 8 of 64 experts held, an eighth of the vocabulary,
+# 2 x 2048 tokens a step (the chip benchmark's deepseek-v2-lite-16b).
+DS = get_config("deepseek-v2-lite-16b").replace(
+    n_layers=5, n_experts=8, n_routed_experts=64, vocab=12800,
+    dtype="float32", analog=True, analog_mode="device")
+DS_TOKENS = 2 * 2048
+MOE_NAMES = ("moe.route", "moe.dispatch", "moe.experts")
+
+
+@pytest.mark.parametrize("kernel", ["vmm", "mvm", "write"])
+def test_expert_kernels_compile_with_row_counts(one_chip, kernel):
+    """The expert-batched read (its row counts an SMEM operand, a dynamic
+    trip count over the token strips) and write (the counts a
+    scalar-prefetch operand of its index maps) for the 8 held experts'
+    [up | gate] grids, over a dropless 4096-row buffer: a read block of
+    4096 tokens fits the VMEM cap."""
+    s = lambda *shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    e, k, n = DS.n_experts, DS.d_model, 2 * DS.d_ff_expert
+    rows = s(e, dt=jnp.int32)
+    if kernel == "write":
+        c = _compile(lambda g, x, d, m, seed, r: xbar_outer_update_inline(
+            g, x, d, m, XCFG, seed=seed, noise_mode="kernel",
+            impl="pallas", rows=r),
+            s(e, k, n), s(e, DS_TOKENS, k), s(e, DS_TOKENS, n), s(e),
+            s(dt=jnp.uint32), rows)
+    else:
+        transpose = kernel == "mvm"
+        c = _compile(lambda x, g, r, w, cnt: xbar_fused_read_inline(
+            x, g, r, w, XCFG, transpose=transpose, impl="pallas",
+            rows=cnt),
+            s(e, DS_TOKENS, n if transpose else k), s(e, k, n), s(e, k, n),
+            s(e), rows)
+    assert KERNEL in c.as_text()
+
+
+def test_deepseek_step_compiles_and_names_the_moe_layer(one_chip):
+    """The whole 5-layer step for one chip: MLA, the dense layer's MLP,
+    the held experts' dropless dispatch and their counted kernels, with
+    the expert layer's names (``moe.route``, ``moe.dispatch``,
+    ``moe.experts``) in the compiled program's metadata."""
+    state = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_state(
+                             jax.random.PRNGKey(0), DS)))
+    tok = _spec(one_chip, (2, 2048), jnp.int32)
+    key = _spec(one_chip, (2,), jnp.uint32)
+    step = make_analog_sgd_step(DS, lr=0.05, impl="pallas",
+                                read_impl="pallas")
+    with jax.default_matmul_precision("highest"):
+        c = step._step.lower(state, {"tokens": tok, "labels": tok},
+                             key).compile()
+    text = c.as_text()
+    assert text.count(KERNEL) >= 3 * 14
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    assert set(MOE_NAMES) <= _stack_names(ops)
