@@ -14,7 +14,11 @@ RA102  ``split_tapes`` containment: the differentiated tree holds tape
        slots only; g/ref/w_scale must live in the frozen tree (the
        symbolic-zero contract — a conductance leaf in the diff tree
        re-enters autodiff and the grads tree silently grows rank-2
-       gradients the update path would shadow).
+       gradients the update path would shadow).  Every tape site carries
+       the scale slot beside its code tapes (codes without their scales
+       are no write operands), and the code tapes take the dtype that
+       holds the write drivers' codes exactly (bfloat16 for the paper's
+       coders: half the bytes of the stacked tapes).
 RA103  collectives: the exact-mode shard_map body may contain no
        collective at all by default — the manual-collective read keeps
        conductances shard-local, so a bare ``all_gather`` in the body is
@@ -182,21 +186,40 @@ def check_donation(lowered_text: str, entry: str) -> List[Finding]:
         "state is double-buffered", entry=entry)]
 
 
-def check_tape_containment(diff, frozen, entry: str) -> List[Finding]:
-    """RA102 over the (diff, frozen) trees from ``split_tapes``."""
+def check_tape_containment(diff, frozen, entry: str,
+                           code_dtype=None) -> List[Finding]:
+    """RA102 over the (diff, frozen) trees from ``split_tapes``;
+    ``code_dtype``, where given, is the dtype the code tapes must have."""
+    import numpy as np
+    from repro.core.tiled_analog import TAPE_LEAVES
+
     findings: List[Finding] = []
     hoisted = ("g", "ref", "w_scale")
 
     def walk_diff(p, path):
         if isinstance(p, dict):
             if "x_tape" in p or "d_tape" in p:
-                leaked = sorted(set(p) - {"x_tape", "d_tape", "n_tape"})
+                site = "/".join(path)
+                leaked = sorted(set(p) - set(TAPE_LEAVES))
                 if leaked:
                     findings.append(Finding(
-                        "RA102", f"tape site {'/'.join(path)} carries "
+                        "RA102", f"tape site {site} carries "
                         f"non-tape leaves {leaked} in the differentiated "
                         "tree (conductances re-enter autodiff)",
                         entry=entry))
+                if "s_tape" not in p:
+                    findings.append(Finding(
+                        "RA102", f"tape site {site} carries code tapes "
+                        "without their scale slot s_tape", entry=entry))
+                wrong = sorted(k for k in ("x_tape", "d_tape")
+                               if code_dtype is not None and k in p
+                               and np.dtype(p[k].dtype)
+                               != np.dtype(code_dtype))
+                if wrong:
+                    findings.append(Finding(
+                        "RA102", f"tape site {site}: {wrong} are not "
+                        f"{np.dtype(code_dtype).name}, the dtype that holds "
+                        "the write drivers' codes", entry=entry))
             elif any(k in p for k in hoisted):
                 found = sorted(k for k in hoisted if k in p)
                 findings.append(Finding(
@@ -256,7 +279,7 @@ def _key_struct():
 
 def _audit_unsharded_step(arch: str) -> List[Finding]:
     import jax
-    from repro.core.tiled_analog import split_tapes
+    from repro.core.xbar_ops import update_code_dtype
     from repro.train.analog_lm import AnalogTrainStep
 
     entry = f"train_step[{arch},exact,unsharded]"
@@ -272,9 +295,9 @@ def _audit_unsharded_step(arch: str) -> List[Finding]:
     findings += check_clip_round_budget(closed, entry)
     findings += check_donation(
         step._step.lower(state, batch, key).as_text(), entry)
-    diff, frozen = split_tapes(state["params"],
-                               int(batch["tokens"].size))
-    findings += check_tape_containment(diff, frozen, entry)
+    diff, frozen = step.split_tapes(state["params"], batch)
+    findings += check_tape_containment(diff, frozen, entry,
+                                       update_code_dtype(step.xcfg))
     return findings
 
 
@@ -431,8 +454,10 @@ def _sharded_update_args():
              "x_tape": P(None, None, "model"),
              "d_tape": P(None, None, None),
              "scale": P()}
-    args = (S((L, K, N), jnp.float32), S((L, B, K), jnp.float32),
-            S((L, B, N), jnp.float32), S((L,), jnp.float32))
+    # The drives as the train step hands them over: the write drivers'
+    # codes in bfloat16 (kernels/xbar_update.write_operands).
+    args = (S((L, K, N), jnp.float32), S((L, B, K), jnp.bfloat16),
+            S((L, B, N), jnp.bfloat16), S((L,), jnp.float32))
     return cfg, specs, args
 
 

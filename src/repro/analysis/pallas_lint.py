@@ -303,6 +303,8 @@ def _kernel_entries() -> List[Tuple[str, Callable, tuple, dict]]:
     g = S((L, K, N), f32)
     x = S((L, B, K), f32)
     d = S((L, B, N), f32)
+    xc = S((L, B, K), jnp.bfloat16)
+    dc = S((L, B, N), jnp.bfloat16)
     seed = S((), jnp.uint32)
     # Fused-read operands: K/N are deliberately ragged against the 16x16
     # tile (40 = 2.5 tiles, 24 = 1.5 tiles) so the wrapper's padding and
@@ -341,6 +343,19 @@ def _kernel_entries() -> List[Tuple[str, Callable, tuple, dict]]:
                  noise_mode="none", impl="interpret",
                  update_mode="pulse_train"),
          (g, x, d, 1.0e-3), {}),
+        # The drives as the train step hands them over: the write
+        # drivers' codes in bfloat16, which the kernel contracts in one
+        # pass (two for pulse trains); their tape blocks are half the
+        # bytes of the float32 ones.
+        ("xbar_outer_update[codes]",
+         partial(xbar_outer_update, cfg=cfg, block_b=4,
+                 noise_mode="kernel", impl="interpret"),
+         (g, xc, dc, 1.0e-3), {"seed": seed}),
+        ("xbar_outer_update[codes-pulse-train]",
+         partial(xbar_outer_update, cfg=cfg, block_b=4,
+                 noise_mode="kernel", impl="interpret",
+                 update_mode="pulse_train"),
+         (g, xc, dc, 1.0e-3), {"seed": seed}),
         ("xbar_fused_read[vmm]",
          fused,
          (S((B, K), f32), S((K, N), f32), S((K, N), f32), 1.0), {}),

@@ -49,12 +49,13 @@ EXPERT_BATCHED = "expert_batched"
 
 KINDS = (COLUMN_PARALLEL, ROW_PARALLEL, EXPERT_BATCHED)
 
-#: Leaf names of a tiled-crossbar container (plus the in-step tape slots).
-#: ``g_carry`` is the optional periodic-carry LSB array (paper §V.C) —
-#: present only when the config enables carry, shaped and sharded exactly
-#: like ``g``.
+#: Leaf names of a tiled-crossbar container (plus the in-step tape slots:
+#: the write drivers' codes, their scale pair and an expert stack's routed
+#: row counts, ``core.tiled_analog.TAPE_LEAVES``).  ``g_carry`` is the
+#: optional periodic-carry LSB array (paper §V.C) — present only when the
+#: config enables carry, shaped and sharded exactly like ``g``.
 ANALOG_LEAVES = ("g", "ref", "w_scale", "g_carry", "x_tape", "d_tape",
-                 "n_tape")
+                 "s_tape", "n_tape")
 
 #: Projection keys whose K (row) tiles follow the TP axis — the analog
 #: mirror of the digital row-parallel rule.
@@ -215,15 +216,18 @@ def leaf_layout(kind: str, ndim: int, leaf: str, rows: int, cols: int
     may only split at (1 for non-tiled dims).  ``None`` = replicated.
 
     The layer dim of a scan-stacked container is never sharded (it is the
-    scan axis); ``w_scale`` shards exactly like its container's lead dims
-    (per-expert scales follow their experts).
+    scan axis); ``w_scale`` and the write's scale pairs ``s_tape`` (lead
+    dims, then the pair) shard exactly like their container's lead dims
+    (per-expert values follow their experts).
     """
-    lead = ndim if leaf == "w_scale" else ndim - 2
+    lead = {"w_scale": ndim, "s_tape": ndim - 1}.get(leaf, ndim - 2)
     roles: list = [(None, 1)] * lead
     if kind == EXPERT_BATCHED and lead >= 1:
         roles[lead - 1] = ("ep", 1)
     if leaf == "w_scale":
         return tuple(roles)
+    if leaf == "s_tape":
+        return (*roles, (None, 1))
     if kind == EXPERT_BATCHED:
         r, c = ("fsdp", rows), (None, 1)
     elif kind == ROW_PARALLEL:

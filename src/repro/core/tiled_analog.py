@@ -22,11 +22,12 @@ hwmodel cost roll-up projects at the paper's Table-I geometry — see
 ``hwmodel/arch_cost.train_step_cost``.
 
 In-situ training needs the *drive operands* of the outer-product write —
-the quantised activations x_q and errors d_q — not a materialised (K, N)
-gradient.  The custom VJP here therefore returns **symbolic-zero**
-cotangents for g/ref/w_scale (zero by type: nothing is traced, nothing is
-broadcast) and instead writes x_q / d_q into two tape leaves.  The train
-step hoists the analog leaves out of the differentiated tree entirely
+the write drivers' integer codes of the activations x and errors d, and
+their two scales — not a materialised (K, N) gradient.  The custom VJP
+here therefore returns **symbolic-zero** cotangents for g/ref/w_scale
+(zero by type: nothing is traced, nothing is broadcast) and instead
+writes the codes into two tape leaves and the scales into a third.  The
+train step hoists the analog leaves out of the differentiated tree entirely
 (:func:`split_tapes` / :func:`merge_tapes`), so the grads tree holds
 exactly the tapes plus the digital gradients, and the analog optimizer
 hands the tapes straight to the fused Pallas kernel
@@ -55,7 +56,7 @@ from .crossbar import CrossbarConfig, make_reference, tile_grid, \
     weights_to_conductance
 from .device import IDEAL, LINEARIZED, TAOX, TAOX_NONOISE, DeviceConfig
 from .shardctx import suspended_shard_context
-from .xbar_ops import mvm, quantize_update_operands, vmm
+from .xbar_ops import mvm, quantize_update_codes, vmm
 
 Array = jax.Array
 
@@ -232,22 +233,27 @@ def _mvm_any(d: Array, g: Array, ref: Array, w_scale, cfg,
 def _quantize_operands_any(x: Array, d: Array, cfg):
     """Write-driver quantisation, per matrix of a batched container: the
     full-scale calibration of the temporal/voltage coders is per physical
-    array, so each expert quantises against its own operand range."""
+    array, so each expert quantises against its own operand range.
+    Returns the integer codes and each matrix's (x_scale, d_scale) pair
+    along a last dim of 2."""
+
+    def codes(xx, dd):
+        x_int, d_int, x_scale, d_scale = quantize_update_codes(xx, dd, cfg)
+        return x_int, d_int, jnp.stack([x_scale, d_scale])
+
     with jax.named_scope("xbar.write"):
-        if x.ndim == 2:
-            return quantize_update_operands(x, d, cfg)
-        return jax.vmap(
-            lambda xx, dd: quantize_update_operands(xx, dd, cfg))(x, d)
+        return codes(x, d) if x.ndim == 2 else jax.vmap(codes)(x, d)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+@partial(jax.custom_vjp, nondiff_argnums=(9, 10, 11))
 def _taped_matmul(g: Array, ref: Array, w_scale: Array,
-                  x_tape: Array, d_tape: Array, n_tape, rows, x: Array,
-                  cfg: CrossbarConfig, meta=None) -> Array:
-    """y = read(x); its VJP deposits the write operands in the tapes, and
-    for an expert-batched stack its routed row counts ``rows`` in
-    ``n_tape`` (both ``None`` for other containers)."""
-    del x_tape, d_tape, n_tape
+                  x_tape: Array, d_tape: Array, s_tape: Array, n_tape, rows,
+                  x: Array, cfg: CrossbarConfig, meta, tape_dtypes) -> Array:
+    """y = read(x); its VJP deposits the write drivers' codes in the
+    tapes (in ``tape_dtypes``, the slots' own), their scales in
+    ``s_tape``, and for an expert-batched stack its routed row counts
+    ``rows`` in ``n_tape`` (both ``None`` for other containers)."""
+    del x_tape, d_tape, s_tape, n_tape
     return _vmm_any(x, g, ref, w_scale, cfg, meta, rows)
 
 
@@ -255,17 +261,18 @@ def _value(a):
     return None if a is None else a.value
 
 
-def _taped_fwd(g, ref, w_scale, x_tape, d_tape, n_tape, rows, x, cfg, meta):
+def _taped_fwd(g, ref, w_scale, x_tape, d_tape, s_tape, n_tape, rows, x,
+               cfg, meta, tape_dtypes):
     # defvjp(..., symbolic_zeros=True) wraps every differentiable primal as
     # CustomVJPPrimal(value, perturbed); the tapes' values are never read.
-    del x_tape, d_tape, n_tape
+    del x_tape, d_tape, s_tape, n_tape, tape_dtypes
     g, ref, w_scale, x = g.value, ref.value, w_scale.value, x.value
     rows = _value(rows)
     y = _vmm_any(x, g, ref, w_scale, cfg, meta, rows)
     return y, (g, ref, w_scale, rows, x)
 
 
-def _taped_bwd(cfg, meta, res, dy):
+def _taped_bwd(cfg, meta, tape_dtypes, res, dy):
     g, ref, w_scale, rows, x = res
     if isinstance(dy, SymbolicZero):  # y unused downstream: nothing flows
         dy = jnp.zeros(dy.aval.shape, dy.aval.dtype)
@@ -275,28 +282,41 @@ def _taped_bwd(cfg, meta, res, dy):
     # count carry a zero error, as they carried a zero drive.
     dx = _mvm_any(dy32, g, ref, w_scale, cfg, meta, rows)
     # The write drivers' operands, quantised exactly as the hardware does
-    # (rows: temporal code, columns: voltage code).  They flow out through
-    # the tape leaves; g/ref/w_scale get *symbolic* zero cotangents — the
-    # dense (K, N) gradient is never formed, not even as a zeros fill.
-    x_q, d_q = _quantize_operands_any(x.astype(jnp.float32), dy32, cfg)
+    # (rows: temporal code, columns: voltage code).  Their codes flow out
+    # through the tape leaves and their scales through ``s_tape``;
+    # g/ref/w_scale get *symbolic* zero cotangents — the dense (K, N)
+    # gradient is never formed, not even as a zeros fill.
+    x_int, d_int, scales = _quantize_operands_any(x.astype(jnp.float32),
+                                                  dy32, cfg)
     if rows is None:
         n_q = rows_ct = None
     else:  # the counts ride to the write through the n_tape cotangent
         n_q, rows_ct = rows.astype(jnp.float32), _symbolic_zero(rows)
     return (_symbolic_zero(g), _symbolic_zero(ref), _symbolic_zero(w_scale),
-            x_q, d_q, n_q, rows_ct, dx.astype(x.dtype))
+            x_int.astype(tape_dtypes[0]), d_int.astype(tape_dtypes[1]), scales,
+            n_q, rows_ct, dx.astype(x.dtype))
 
 
 _taped_matmul.defvjp(_taped_fwd, _taped_bwd, symbolic_zeros=True)
 
 
+def _tape_slots(p: dict, rows: tuple, k: int, n: int) -> tuple:
+    """The container's (x_tape, d_tape, s_tape), or throwaway zero slots
+    for ``rows`` operand rows where it carries none."""
+    names = ("x_tape", "d_tape", "s_tape")
+    if not all(name in p for name in names):
+        p = {**make_tapes({"g": jax.ShapeDtypeStruct((k, n), jnp.float32)},
+                          rows), **p}
+    return tuple(p[name] for name in names)
+
+
 def analog_project(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
     """Apply a programmed container to activations of shape (..., K).
 
-    If the container carries ``x_tape``/``d_tape`` leaves (injected by the
-    analog train step), the backward pass deposits the quantised update
-    operands there; otherwise throwaway zero tapes are created (inference /
-    eval — no backward, no cost).
+    If the container carries tape leaves (injected by the analog train
+    step), the backward pass deposits the write drivers' codes and scales
+    there; otherwise throwaway zero tapes are created (inference / eval —
+    no backward, no cost).
 
     Each container must be applied at most once per differentiated step:
     cotangents of a reused container would *sum* the tapes, which is not
@@ -309,15 +329,11 @@ def analog_project(p: dict, x: Array, cfg: CrossbarConfig) -> Array:
     # tapes are globally shaped, so geometry comes from the static meta.
     k, n = meta.view(2) if meta is not None else p["g"].shape
     xb = x.reshape(-1, k)
-    x_tape = p.get("x_tape")
-    d_tape = p.get("d_tape")
-    if x_tape is None:
-        x_tape = jnp.zeros((xb.shape[0], k), jnp.float32)
-    if d_tape is None:
-        d_tape = jnp.zeros((xb.shape[0], n), jnp.float32)
+    x_tape, d_tape, s_tape = _tape_slots(p, (xb.shape[0],), k, n)
     # audit: allow RA103 -- ordered partial-sum/output combines of the shard-local read (shardctx.combine_partials_exact, anchored here by the custom_vjp call site): arithmetic-free activation-sized gathers in pinned order; RA107 bounds their compiled byte size
     y = _taped_matmul(effective_g(p, cfg), p["ref"], p["w_scale"], x_tape,
-                      d_tape, None, None, xb.astype(jnp.float32), cfg, meta)
+                      d_tape, s_tape, None, None, xb.astype(jnp.float32),
+                      cfg, meta, (x_tape.dtype, d_tape.dtype))
     return y.reshape(*lead, n).astype(x.dtype)
 
 
@@ -341,12 +357,7 @@ def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig,
     if x.shape[0] != e or x.shape[-1] != k:
         raise ValueError(f"expert-batched x {x.shape} does not match "
                          f"container {p['g'].shape}")
-    x_tape = p.get("x_tape")
-    d_tape = p.get("d_tape")
-    if x_tape is None:
-        x_tape = jnp.zeros(x.shape, jnp.float32)
-    if d_tape is None:
-        d_tape = jnp.zeros((e, x.shape[1], n), jnp.float32)
+    x_tape, d_tape, s_tape = _tape_slots(p, x.shape[:2], k, n)
     n_tape = p.get("n_tape")
     if rows is not None:
         rows = rows.astype(jnp.float32)
@@ -356,25 +367,27 @@ def analog_project_batched(p: dict, x: Array, cfg: CrossbarConfig,
         n_tape = None
     # audit: allow RA103 -- ordered EP-dispatch/partial-sum combines of the shard-local expert read (shardctx.combine_partials_exact, anchored here by the custom_vjp call site): arithmetic-free capacity-buffer gathers in pinned order; RA107 bounds their compiled byte size
     y = _taped_matmul(effective_g(p, cfg), p["ref"], p["w_scale"], x_tape,
-                      d_tape, n_tape, rows, x.astype(jnp.float32), cfg, meta)
+                      d_tape, s_tape, n_tape, rows, x.astype(jnp.float32),
+                      cfg, meta, (x_tape.dtype, d_tape.dtype))
     return y.astype(x.dtype)
 
 
-#: The in-step tape slots of a container: the write operands, and an
-#: expert-batched stack's routed row counts.
-TAPE_LEAVES = ("x_tape", "d_tape", "n_tape")
+#: The in-step tape slots of a container: the write drivers' codes, their
+#: (x_scale, d_scale) pair per application, and an expert-batched stack's
+#: routed row counts.
+TAPE_LEAVES = ("x_tape", "d_tape", "s_tape", "n_tape")
 
 
 def pop_tapes(params):
     """Strip the tape leaves off every container in a (sub)tree.
 
     Returns ``(clean, tapes, found)``: ``clean`` is the tree without
-    x_tape/d_tape, ``tapes`` mirrors it with ``{"x_tape", "d_tape"}``
-    dicts at container sites (empty dicts elsewhere), ``found`` says
-    whether any tape leaf existed.  Used by the hybrid stack to turn the
-    shared block's per-application tape dim into scan xs — each group
-    boundary consumes its own slice (:func:`push_tapes`) so a weight set
-    applied G times per step tapes G distinct operand blocks.
+    the tape leaves, ``tapes`` mirrors it with dicts of them at container
+    sites (empty dicts elsewhere), ``found`` says whether any tape leaf
+    existed.  Used by the hybrid stack to turn the shared block's
+    per-application tape dim into scan xs — each group boundary consumes
+    its own slice (:func:`push_tapes`) so a weight set applied G times per
+    step tapes G distinct operand blocks.
     """
     if is_analog_container(params):
         tapes = {k: params[k] for k in TAPE_LEAVES if k in params}
@@ -398,17 +411,21 @@ def push_tapes(params, tapes):
     return params
 
 
-def make_tapes(p: dict, n_tokens, counted: bool = False) -> dict:
-    """Zero tape *slots* for one container (shapes (T, K) / (T, N)).
+def make_tapes(p: dict, n_tokens, counted: bool = False,
+               code_dtype=jnp.float32) -> dict:
+    """Zero tape *slots* for one container (shapes (T, K) / (T, N), and
+    (2,) for the scale pair).
 
     Tape lifecycle: the train step allocates these slots (inside jit they
     are zero constants whose values are never read — the taped VJP ignores
     them and XLA folds them away, so no (T, K) buffer is ever written), the
     backward pass of ``_taped_matmul`` overwrites their cotangents with the
-    quantised write-driver operands (x_q, d_q), and the analog optimizer
+    write drivers' integer codes (in ``code_dtype``: the train step's
+    ``xbar_ops.update_code_dtype``, bfloat16 for the paper's coders) and
+    each application's (x_scale, d_scale) pair, and the analog optimizer
     consumes those cotangents as the drive operands of the fused parallel
-    write (``kernels/xbar_update.py``).  One allocation site, one writer,
-    one consumer.
+    write (``kernels/xbar_update.write_operands``).  One allocation site,
+    one writer, one consumer.
 
     ``n_tokens`` may be a tuple: the operand-row shape between the
     container's own lead dims and the feature dim — ``(T,)`` for the
@@ -426,8 +443,9 @@ def make_tapes(p: dict, n_tokens, counted: bool = False) -> dict:
     k, n = gshape[-2:]
     lead = gshape[:-2]  # scan-stacked containers carry (L, K, N)
     rows = n_tokens if isinstance(n_tokens, tuple) else (n_tokens,)
-    tapes = {"x_tape": jnp.zeros((*lead, *rows, k), jnp.float32),
-             "d_tape": jnp.zeros((*lead, *rows, n), jnp.float32)}
+    tapes = {"x_tape": jnp.zeros((*lead, *rows, k), code_dtype),
+             "d_tape": jnp.zeros((*lead, *rows, n), code_dtype),
+             "s_tape": jnp.zeros((*lead, *rows[:-1], 2), jnp.float32)}
     if counted:
         tapes["n_tape"] = jnp.zeros(lead, jnp.float32)
     return tapes
@@ -456,7 +474,7 @@ def with_tapes(params, n_tokens: int, tokens_for=None, path=()):
 
 
 def split_tapes(params, n_tokens: int, tokens_for=None, path=(),
-                counted=None):
+                counted=None, code_dtype=jnp.float32):
     """Partition a parameter tree for the hoisted analog gradient.
 
     Returns ``(diff, frozen)``: ``diff`` carries every digital leaf plus,
@@ -472,19 +490,21 @@ def split_tapes(params, n_tokens: int, tokens_for=None, path=(),
     :func:`with_tapes` — the analog train step passes the registry's
     family-aware resolver so MoE expert tapes hold the dropless buffer
     and the hybrid shared block tapes one slot per group application.
-    ``counted(path)`` says which containers take an ``n_tape`` slot.
+    ``counted(path)`` says which containers take an ``n_tape`` slot, and
+    ``code_dtype`` is the dtype of the code tapes (:func:`make_tapes`).
     """
     if is_analog_container(params):
         rows = tokens_for(path, params["g"].shape) if tokens_for \
             else n_tokens
         return (make_tapes(params, rows,
-                           counted=bool(counted and counted(path))),
+                           counted=bool(counted and counted(path)),
+                           code_dtype=code_dtype),
                 {k: params[k]
                  for k in ("g", "ref", "w_scale", "g_carry", "tp_meta")
                  if k in params})
     if isinstance(params, dict):
         split = {k: split_tapes(v, n_tokens, tokens_for, path + (k,),
-                                counted)
+                                counted, code_dtype)
                  for k, v in params.items()}
         return ({k: v[0] for k, v in split.items()},
                 {k: v[1] for k, v in split.items()})

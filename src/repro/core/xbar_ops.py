@@ -193,18 +193,46 @@ def mvm(d: Array, g: Array, g_ref: Array, w_scale: Array,
                  meta=meta, rows=rows)
 
 
-def quantize_update_operands(
+def _col_coder(cfg: CrossbarConfig) -> AdcConfig:
+    """The column write driver's voltage coder (``upd_col_bits``)."""
+    return AdcConfig(in_bits=cfg.upd_col_bits, out_bits=cfg.adc.out_bits)
+
+
+def update_code_levels(cfg: CrossbarConfig) -> Tuple[int, int]:
+    """Magnitude levels of the row (temporal) and column (voltage) write
+    coders: their codes are integers in [-levels, levels]."""
+    return cfg.adc.in_levels, _col_coder(cfg).in_levels
+
+
+def update_code_dtype(cfg: CrossbarConfig):
+    """dtype that carries the write drivers' codes: bfloat16 where its
+    8-bit significand holds every code exactly (levels up to 2**8),
+    float32 otherwise."""
+    return jnp.bfloat16 if max(update_code_levels(cfg)) <= 256 \
+        else jnp.float32
+
+
+def quantize_update_codes(
         x: Array, d: Array, cfg: CrossbarConfig
-) -> Tuple[Array, Array]:
+) -> Tuple[Array, Array, Array, Array]:
     """Quantise the outer-product operands as the write drivers do.
 
     Rows (x) use the temporal coder (``in_bits``); columns (d) use the
     voltage coder (``upd_col_bits``: 3 magnitude bits + sign in the paper).
-    Returns dequantised (x_q, d_q).
+    Returns the integer codes and their scalar scales
+    (x_int, d_int, x_scale, d_scale), with x ~ x_int * x_scale.
     """
     x_int, x_scale = quantize_input(x, cfg.adc)
-    col_cfg = AdcConfig(in_bits=cfg.upd_col_bits, out_bits=cfg.adc.out_bits)
-    d_int, d_scale = quantize_input(d, col_cfg)
+    d_int, d_scale = quantize_input(d, _col_coder(cfg))
+    return x_int, d_int, x_scale, d_scale
+
+
+def quantize_update_operands(
+        x: Array, d: Array, cfg: CrossbarConfig
+) -> Tuple[Array, Array]:
+    """The write drivers' operands dequantised: (x_int * x_scale,
+    d_int * d_scale) of :func:`quantize_update_codes`."""
+    x_int, d_int, x_scale, d_scale = quantize_update_codes(x, d, cfg)
     return x_int * x_scale, d_int * d_scale
 
 

@@ -27,6 +27,22 @@ new conductances in place, one row strip at a time (the in-kernel normals
 and the epilogue's temporaries stay strip-sized, and so does the compiled
 body).
 
+Contraction
+-----------
+The train step hands the kernel the write drivers' integer codes, not
+their dequantised values (:func:`write_operands`): row codes up to 127
+(the 8-bit temporal coder) and column codes up to 7 (the 4-bit voltage
+coder), in bfloat16, which holds them exactly, with the two scalar scales
+of each matrix folded into its per-layer scale.  Every product is then an
+exact integer in float32, and so is every partial sum below 2**24, so one
+bfloat16 MXU pass with float32 accumulation (two for pulse trains, whose
+|x| |d| accumulator is a second contraction) forms the exact integer
+outer product, which the epilogue scales once.  Float32 operands (the
+dequantised values public callers pass, and the train step's fallback
+where the codes or their sums would not be exact) take the full-precision
+float32 dot, six passes on the MXU.  The kernel tells the two apart by
+the operands' dtype.
+
 Stochasticity
 -------------
 Three modes (``noise_mode``):
@@ -100,6 +116,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.crossbar import CrossbarConfig
 from repro.core.device import DeviceConfig
+from repro.core.xbar_ops import update_code_dtype, update_code_levels
 from repro.kernels.xbar_vmm import VMEM_LIMIT_CAP
 
 Array = jax.Array
@@ -114,6 +131,15 @@ IMPLS = ("auto", "pallas", "interpret", "fused")
 UPDATE_MODES = ("outer", "pulse_train")
 # Full f32 contractions on every backend (see kernels/xbar_vmm.py).
 _HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _contract_precision(dtype):
+    """Precision of the outer-product contraction of operands of
+    ``dtype``: one MXU pass for bfloat16 codes, whose products float32
+    holds exactly, full float32 otherwise.  DEFAULT, stated: a float32
+    model sets JAX's default matmul precision to HIGHEST, which Mosaic
+    refuses on bfloat16 operands."""
+    return jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16 else _HIGHEST
 
 
 # --------------------------------------------------------------------------
@@ -355,17 +381,19 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
         if a_ref is not None:
             a_ref[0, :, :] = jnp.zeros_like(a_ref[0, :, :])
 
+    precision = _contract_precision(x_ref.dtype)
+
     def accumulate():
         # The outer product sum_b x[b, :] d[b, :] for this tile.
         o_ref[0, :, :] += jax.lax.dot_general(
             x_ref[0, :, :], d_ref[0, :, :],
             dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=_HIGHEST)
+            preferred_element_type=jnp.float32, precision=precision)
         if a_ref is not None:
             a_ref[0, :, :] += jax.lax.dot_general(
                 jnp.abs(x_ref[0, :, :]), jnp.abs(d_ref[0, :, :]),
                 dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32, precision=_HIGHEST)
+                preferred_element_type=jnp.float32, precision=precision)
 
     if counted:
         pl.when(filled)(accumulate)
@@ -408,16 +436,16 @@ def _update_kernel(*refs, cfg: CrossbarConfig, n_bsteps: int,
 
 
 def _update_vmem_bytes(b: int, cfg: CrossbarConfig, noise_mode: str,
-                      update_mode: str) -> int:
+                      update_mode: str, tape_bytes: int) -> int:
     """Scoped VMEM the update kernel needs for a ``b``-token block: the
-    double-buffered tape blocks and tile-sized operand/output blocks, the
-    outer-product temporaries, and 4 MiB of slack for the epilogue strips
-    and Mosaic's own scratch."""
+    double-buffered tape blocks (``tape_bytes`` a value) and tile-sized
+    operand/output blocks, the outer-product temporaries, and 4 MiB of
+    slack for the epilogue strips and Mosaic's own scratch."""
     tile = cfg.rows * cfg.cols
     n_tiles = 2 + (noise_mode == "host") + (update_mode == "pulse_train")
     temps = (1 + (update_mode == "pulse_train")) * tile
-    return (4 * (2 * (b * (cfg.rows + cfg.cols) + n_tiles * tile) + temps)
-            + (4 << 20))
+    return (2 * (tape_bytes * b * (cfg.rows + cfg.cols) + 4 * n_tiles * tile)
+            + 4 * temps + (4 << 20))
 
 
 # Token block of the update's batch grid axis.  The outer-product sum
@@ -499,7 +527,8 @@ def _pallas_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=min(
             VMEM_LIMIT_CAP,
-            _update_vmem_bytes(bb, cfg, noise_mode, update_mode))),
+            _update_vmem_bytes(bb, cfg, noise_mode, update_mode,
+                               x_q.dtype.itemsize))),
         interpret=interpret,
         name="xbar_update",
         **call,
@@ -513,9 +542,12 @@ def _fused_update(g, x_q, d_q, scale, noise, seed, offs, cfg, noise_mode,
                   update_mode="outer"):
     """Single-sweep jnp twin of the kernel: one layer-batched einsum plus
     the identical epilogue (and, in kernel noise mode, the identical
-    counter-PRNG bits).  The fast path on hosts without Mosaic."""
+    counter-PRNG bits).  The fast path on hosts without Mosaic.  Its
+    contraction of bfloat16 codes is exact too, so kernel and twin agree
+    on the accumulator bit for bit."""
+    precision = _contract_precision(x_q.dtype)
     acc = jnp.einsum("lbk,lbn->lkn", x_q, d_q,
-                     preferred_element_type=jnp.float32, precision=_HIGHEST)
+                     preferred_element_type=jnp.float32, precision=precision)
     if noise_mode == "kernel":
         noise = field_normals(seed, g.shape, cfg, tile_offsets=offs)
     elif noise_mode == "none":
@@ -523,7 +555,7 @@ def _fused_update(g, x_q, d_q, scale, noise, seed, offs, cfg, noise_mode,
     if update_mode == "pulse_train":
         a_abs = jnp.einsum("lbk,lbn->lkn", jnp.abs(x_q), jnp.abs(d_q),
                            preferred_element_type=jnp.float32,
-                           precision=_HIGHEST)
+                           precision=precision)
         return _pulse_epilogue(g, acc, a_abs, scale[:, None, None], noise,
                                cfg.device)
     return _device_epilogue(g, scale[:, None, None] * acc, noise,
@@ -544,6 +576,51 @@ def _dispatch_update(g, x_q, d_q, scale, noise, seed, offs, cfg, block_b,
 
 _outer_update = functools.partial(jax.jit, static_argnames=(
     "cfg", "block_b", "impl", "noise_mode", "update_mode"))(_dispatch_update)
+
+
+def _drive_dtype(x_q: Array, d_q: Array) -> tuple:
+    """The drive operands as the kernel takes them: a bfloat16 pair (the
+    write drivers' codes) as it is, anything else in float32."""
+    if x_q.dtype == d_q.dtype == jnp.bfloat16:
+        return x_q, d_q
+    return x_q.astype(jnp.float32), d_q.astype(jnp.float32)
+
+
+def write_one_pass(cfg: CrossbarConfig, tokens: int) -> bool:
+    """Whether one bfloat16 MXU pass contracts ``tokens`` rows of the
+    write drivers' codes exactly: bfloat16 holds every code (both coders'
+    levels up to 2**8), and the largest sum of products,
+    ``levels_x * levels_d * tokens``, stays below 2**24, where float32
+    still holds every integer."""
+    lx, ld = update_code_levels(cfg)
+    return (update_code_dtype(cfg) == jnp.bfloat16
+            and lx * ld * tokens < 2 ** 24)
+
+
+def write_operands(x_tape: Array, d_tape: Array, s_tape: Array, scale,
+                   cfg: CrossbarConfig) -> tuple:
+    """The rank-k write's drive operands from one container's tapes.
+
+    ``x_tape`` (lead..., [reps,] T, K) / ``d_tape`` (..., T, N): the
+    write drivers' integer codes; ``s_tape`` (lead..., [reps,] 2): each
+    application's (x_scale, d_scale); ``scale`` (lead...): each matrix's
+    folded ``-lr * w_scale``.  Returns ``(x, d, scale, one_pass)``.
+
+    Where one bfloat16 pass contracts the codes exactly
+    (:func:`write_one_pass`) and each matrix has one scale pair (a reused
+    container tapes one per application), the codes go to the kernel in
+    bfloat16 and both scales fold into ``scale``: the kernel forms the
+    exact integer outer product and scales it once.  Otherwise they are
+    dequantised to the values ``xbar_ops.quantize_update_operands`` gives,
+    bit for bit, for the float32 contraction."""
+    xs, ds = s_tape[..., 0], s_tape[..., 1]
+    one_pass = (s_tape.ndim - 1 == jnp.ndim(scale)
+                and write_one_pass(cfg, x_tape.shape[-2]))
+    if one_pass:
+        return (x_tape.astype(jnp.bfloat16), d_tape.astype(jnp.bfloat16),
+                scale * (xs * ds), True)
+    return (x_tape.astype(jnp.float32) * xs[..., None, None],
+            d_tape.astype(jnp.float32) * ds[..., None, None], scale, False)
 
 
 def _resolve_update_args(g, x_q, d_q, scale, cfg, noise, seed, noise_mode,
@@ -598,8 +675,7 @@ def _resolve_update_args(g, x_q, d_q, scale, cfg, noise, seed, noise_mode,
         raise ValueError(f"update_mode must be one of {UPDATE_MODES}")
 
     g = g.astype(jnp.float32)
-    x_q = x_q.astype(jnp.float32)
-    d_q = d_q.astype(jnp.float32)
+    x_q, d_q = _drive_dtype(x_q, d_q)
     if noise is not None:
         noise = noise.astype(jnp.float32)
     if seed is not None:
@@ -625,7 +701,9 @@ def xbar_outer_update(g: Array, x_q: Array, d_q: Array, scale,
     ``g``: (K, N) or scan-stacked (L, K, N) conductances; ``x_q``: (B, K)
     or (L, B, K) row drives; ``d_q``: (B, N) or (L, B, N) column drives
     (already quantised by the write drivers); ``scale`` folds
-    ``-lr * w_scale`` — scalar or (L,).
+    ``-lr * w_scale`` — scalar or (L,).  A bfloat16 pair of drives is
+    contracted in one MXU pass, anything else in float32 (see the module
+    docstring, and :func:`write_operands` for the codes).
 
     Stochasticity: pass ``seed`` (scalar uint32) for in-kernel noise
     (``noise_mode="kernel"``), or a pre-generated N(0,1) ``noise`` field of
@@ -775,8 +853,7 @@ def xbar_sharded_update(g: Array, x_q: Array, d_q: Array, scale,
             seed=seed_l, noise_mode=noise_mode, impl=impl,
             tile_offsets=offs, update_mode=update_mode)
 
-    operands = [g.astype(jnp.float32), x_q.astype(jnp.float32),
-                d_q.astype(jnp.float32), scale]
+    operands = [g.astype(jnp.float32), *_drive_dtype(x_q, d_q), scale]
     in_specs = [g_spec, x_spec, d_spec, s_spec]
     if use_noise:
         operands.append(noise.astype(jnp.float32))

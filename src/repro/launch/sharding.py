@@ -99,7 +99,8 @@ def analog_container_pspec(sp, shape, cfg: ModelConfig, mesh,
     dim of a scan-stacked container is never sharded (it is the scan
     axis); ``w_scale`` follows its container's lead dims (per-expert
     scales live with their experts).  Tape slots follow their container:
-    x_tape shards its K like g's rows, d_tape its N like g's columns.
+    x_tape shards its K like g's rows, d_tape its N like g's columns, and
+    the scale pairs ``s_tape`` its lead dims like ``w_scale``.
     """
     rows, cols = cfg.analog_rows, cfg.analog_cols
     kind = registry.classify(sp)
@@ -115,9 +116,10 @@ def analog_update_specs(path: Tuple[str, ...], g_shape, cfg: ModelConfig,
     ``path`` is the container's key path in the parameter tree (used to
     pick the registry consumer kind); ``g_shape`` the (possibly
     scan-stacked / expert-batched) conductance shape.  Returns specs for g
-    (also ref), the two tape operands, the per-layer scale and the
-    container's ``w_scale``, all tile-aligned so every shard owns whole
-    tiles and the outer-product contraction (over tokens) stays local.
+    (also ref), the two code tapes and their scale pairs, the per-layer
+    scale and the container's ``w_scale``, all tile-aligned so every shard
+    owns whole tiles and the outer-product contraction (over tokens) stays
+    local.
     """
     sp = list(path)
     lead = g_shape[:-2]
@@ -134,6 +136,8 @@ def analog_update_specs(path: Tuple[str, ...], g_shape, cfg: ModelConfig,
                                          "x_tape"),
         "d_tape": analog_container_pspec(sp, (*tapes_lead, n), cfg, mesh,
                                          "d_tape"),
+        "s_tape": analog_container_pspec(sp, (*lead, 2), cfg, mesh,
+                                         "s_tape"),
         "scale": w_scale_spec,
         "w_scale": w_scale_spec,
     }

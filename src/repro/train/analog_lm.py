@@ -7,8 +7,8 @@ it compiles exactly once and updates conductances in place:
   1. the parameter tree is *split* (``core.tiled_analog.split_tapes``):
      digital leaves plus per-container tape slots form the differentiated
      tree, while every container's g/ref/w_scale is hoisted into frozen
-     (closure) position — the backward pass deposits the quantised
-     write-driver operands (x_q, d_q) in the tape cotangents and no dense
+     (closure) position — the backward pass deposits the write drivers'
+     integer codes and their scales in the tape cotangents and no dense
      (K, N) weight gradient, not even a zeros fill, is ever formed,
   2. forward = VMM, backward = MVM through the same conductances
      (``models/layers.project`` dispatches on the container),
@@ -17,6 +17,9 @@ it compiles exactly once and updates conductances in place:
      ``kernels/xbar_update.xbar_outer_update`` — one sweep over a
      scan-stacked (L, K, N) container (outer product + nonlinear /
      asymmetric / stochastic device model, one HBM round-trip per tile),
+     the codes contracted in one bfloat16 MXU pass where that is exact
+     (``kernels/xbar_update.write_operands``; the step's metric
+     ``write_one_pass_share`` is the share of written cells that were),
      with write noise generated in-kernel from one scalar seed per
      container (``noise_mode="kernel"``; the legacy pre-generated field
      path stays behind ``noise_mode="host"``),
@@ -64,6 +67,7 @@ Use :meth:`shard_state` to lay an initial state out on the mesh.
 from __future__ import annotations
 
 import contextlib
+import sys
 import zlib
 from typing import Dict, Optional, Tuple
 
@@ -81,9 +85,10 @@ from repro.core.periodic_carry import carry_fold
 from repro.core.tiled_analog import (crossbar_from_model,
                                      is_analog_container, merge_tapes,
                                      split_tapes)
+from repro.core.xbar_ops import update_code_dtype
 from repro.hwmodel.arch_cost import train_step_cost
 from repro.kernels.xbar_update import (_flat_axis_index, _mix32,
-                                       _wrap_shard_map,
+                                       _wrap_shard_map, write_operands,
                                        xbar_outer_update_inline,
                                        xbar_sharded_update)
 from repro.models import model as M
@@ -122,7 +127,10 @@ class AnalogTrainStep:
     """Jitted, donated-buffer analog-SGD step: ``state, metrics = step(state,
     batch, key)``.  ``step.compiles`` counts tracings (must stay at 1);
     ``step.cost`` is the projected per-step hardware cost (available after
-    the first call, when the token count is known).
+    the first call, when the token count is known), and
+    ``step.write_one_pass_share`` the share of the written cells whose
+    drive codes the write contracts in one bfloat16 MXU pass (known once
+    the step is traced; the first call prints it).
 
     ``impl`` selects the update-kernel execution path ("pallas" |
     "interpret" | "fused" | None = auto: Mosaic on TPU, the fused jnp twin
@@ -178,6 +186,7 @@ class AnalogTrainStep:
         # reference for parity tests and collective-byte accounting.
         self.read_mode = read_mode
         self.cost: Optional[dict] = None
+        self.write_one_pass_share: Optional[float] = None
         # With a mesh the jit carries explicit in/out shardings (built at
         # first call, when the state structure is known) so the parameter
         # layout is pinned across steps — GSPMD would otherwise be free to
@@ -190,6 +199,15 @@ class AnalogTrainStep:
 
     def __call__(self, state: dict, batch: Dict[str, Array], key: Array
                  ) -> Tuple[dict, Dict[str, Array]]:
+        first = self.cost is None
+        out = self._call(state, batch, key)
+        if first:
+            print(f"analog train step: write_one_pass_share "
+                  f"{self.write_one_pass_share!r}", file=sys.stderr,
+                  flush=True)
+        return out
+
+    def _call(self, state, batch, key):
         if self.cost is None:
             # First call: audit the tree — every projection-family matrix
             # must be a crossbar container (core/analog_registry); a tree
@@ -291,7 +309,6 @@ class AnalogTrainStep:
     def _step_impl(self, state, batch, key):
         cfg = self.cfg
         params = state["params"]
-        n_tokens = batch["tokens"].size  # static under jit
 
         # Sharded + exact (the default contract): this body runs INSIDE
         # shard_map — each device holds its local tile blocks of every
@@ -325,27 +342,19 @@ class AnalogTrainStep:
                 read_params = self._gather_containers(params, ())
 
         # Hoist g/ref/w_scale out of the differentiated arguments: the grads
-        # tree holds exactly the tape cotangents + digital gradients.  The
-        # registry resolves each container's tape route: a dropless slot
-        # per token and expert (with the routed row counts beside them),
-        # one slot block per application for the hybrid shared weights,
-        # n_tokens rows everywhere else.
-        diff, frozen = split_tapes(
-            read_params, n_tokens,
-            tokens_for=lambda path, shape: registry.tape_lead(
-                path, cfg, n_tokens, batch["tokens"].shape),
-            counted=lambda path: registry.classify(path)
-            == registry.EXPERT_BATCHED)
+        # tree holds exactly the tape cotangents + digital gradients.
+        diff, frozen = self.split_tapes(read_params, batch)
         (loss, metrics), grads = jax.value_and_grad(
             lambda d: M.loss_fn(merge_tapes(d, frozen), batch, cfg),
             has_aux=True)(diff)
-        rail = []
+        rail, written = [], []
         # One threefry draw per step; per-container seeds come out of the
         # same counter mix the kernel PRNG uses (keyed on the tree path).
         seed_base = jax.random.bits(key, (), jnp.uint32) \
             if self.xcfg.device.write_noise > 0.0 \
             and self.noise_mode == "kernel" else None
-        new_params = self._update(params, grads, key, seed_base, (), rail)
+        new_params = self._update(params, grads, key, seed_base, (), rail,
+                                  written)
         if self.xcfg.carry and getattr(cfg, "carry_period", 0) > 0:
             # Periodic carry (paper §VI.B): every carry_period steps a
             # serial closed-loop pass folds each container's carry (LSB)
@@ -370,7 +379,28 @@ class AnalogTrainStep:
         # fraction of devices pinned at the conductance rails — the
         # leading indicator of window exhaustion (paper §V.A).
         out["g_rail_frac"] = sum(rail) / len(rail)
+        # Static: the share of the written cells (whole tiles) whose
+        # drive codes went through the one-pass contraction.
+        self.write_one_pass_share = (sum(c for c, one in written if one)
+                                     / sum(c for c, _ in written))
+        out["write_one_pass_share"] = jnp.float32(self.write_one_pass_share)
         return {"params": new_params, "step": state["step"] + 1}, out
+
+    def split_tapes(self, params, batch):
+        """The step's ``core.tiled_analog.split_tapes``: the registry
+        resolves each container's tape route — a dropless slot per token
+        and expert (with the routed row counts beside them), one slot
+        block per application for the hybrid shared weights, n_tokens
+        rows everywhere else — and the code tapes take the dtype that
+        holds the write drivers' codes exactly."""
+        n_tokens = batch["tokens"].size
+        return split_tapes(
+            params, n_tokens,
+            tokens_for=lambda path, shape: registry.tape_lead(
+                path, self.cfg, n_tokens, batch["tokens"].shape),
+            counted=lambda path: registry.classify(path)
+            == registry.EXPERT_BATCHED,
+            code_dtype=update_code_dtype(self.xcfg))
 
     def _annotate_containers(self, p, path):
         """Attach a static ``shardctx.ShardMeta`` to each tile-sharded
@@ -423,7 +453,7 @@ class AnalogTrainStep:
                     for k, v in p.items()}
         return p
 
-    def _update(self, p, g, key, seed_base, path, rail):
+    def _update(self, p, g, key, seed_base, path, rail, written):
         if is_analog_container(p):
             # An expert stack's write is named with its reads
             # (``moe.experts``, models/moe).
@@ -432,14 +462,15 @@ class AnalogTrainStep:
                 else contextlib.nullcontext()
             with outer, jax.named_scope("xbar.write"):
                 return self._update_container(p, g, key, seed_base, path,
-                                              rail)
+                                              rail, written)
         if isinstance(p, dict):
             return {k: self._update(p[k], g[k], key, seed_base,
-                                    path + (k,), rail)
+                                    path + (k,), rail, written)
                     for k in p}
         return p - self.lr * g.astype(p.dtype)
 
-    def _update_container(self, p, tapes, key, seed_base, path, rail):
+    def _update_container(self, p, tapes, key, seed_base, path, rail,
+                          written):
         """The paper's Fig. 3c parallel write, fused on the (L, tiles)
         grid: one kernel sweep per container.  The registry flattens the
         container's lead dims — scan layers, the expert dim of an
@@ -455,7 +486,13 @@ class AnalogTrainStep:
         counts (``n_tape``), which the kernel uses to skip the rest; its
         experts draw the write noise of experts ``first_expert ..`` of
         the whole layer: the flattened layer index is offset by
-        ``first_expert`` experts' worth of rows."""
+        ``first_expert`` experts' worth of rows.
+
+        The tapes hold the write drivers' codes and scale pairs;
+        ``write_operands`` folds the scales into ``scale`` and hands the
+        kernel bfloat16 codes where one MXU pass contracts them exactly,
+        and dequantised operands otherwise.  ``written`` collects each
+        container's (written cells in whole tiles, one pass)."""
         smap = self.mesh is not None and self.exact
         kind = registry.classify(path)
         rows = tapes.get("n_tape")
@@ -487,15 +524,21 @@ class AnalogTrainStep:
         leaf = "g_carry" if "g_carry" in p else "g"
         if leaf == "g_carry":
             scale = scale * jnp.float32(self.xcfg.carry_base)
+        gshape = self._cspecs[path][1] if smap else p["g"].shape
+        rows_t, cols_t = self.xcfg.rows, self.xcfg.cols
+        cells = (int(np.prod(gshape[:-2])) * -(-gshape[-2] // rows_t) * rows_t
+                 * -(-gshape[-1] // cols_t) * cols_t)
         if smap:
-            g_new, railed, total = self._local_block_update(
+            g_new, railed, total, one_pass = self._local_block_update(
                 p[leaf], tapes, scale, noise, seed, mode, path, kind,
                 held_off)
             rail.append(railed / total)
         else:
+            x, d, scale, one_pass = write_operands(
+                tapes["x_tape"], tapes["d_tape"], tapes["s_tape"], scale,
+                self.xcfg)
             g3, x3, d3, s1, n3, unflatten = registry.flatten_lead(
-                kind, p[leaf], tapes["x_tape"], tapes["d_tape"], scale,
-                noise)
+                kind, p[leaf], x, d, scale, noise)
             if self.mesh is not None:  # GSPMD TP path: nested shard_map
                 specs = self._flat_update_specs(path, p["g"].shape, kind)
                 g3_new = xbar_sharded_update(
@@ -516,6 +559,7 @@ class AnalogTrainStep:
             rail.append(jnp.mean(
                 (g_new <= dev.gmin + 1e-3 * span)
                 | (g_new >= dev.gmax - 1e-3 * span)).astype(jnp.float32))
+        written.append((cells, one_pass))
         return {**p, leaf: g_new}
 
     def _carry_readout(self, v):
@@ -577,9 +621,9 @@ class AnalogTrainStep:
         offset the counter-PRNG by the block's global base (layer, tile)
         coordinates, flatten the lead dims, and run the plain
         layer-batched kernel on the local conductances.  Returns
-        (g_new, railed_count, total_cells) with the count psum'd over the
-        sharded axes — 0/1 sums are order-exact, so the rail fraction
-        matches the single-device metric bitwise."""
+        (g_new, railed_count, total_cells, one_pass) with the count
+        psum'd over the sharded axes — 0/1 sums are order-exact, so the
+        rail fraction matches the single-device metric bitwise."""
         specs, gshape = self._cspecs[path]
         mesh = self.mesh
         rows, cols = self.xcfg.rows, self.xcfg.cols
@@ -600,6 +644,7 @@ class AnalogTrainStep:
 
         x_loc = slice_dim(tapes["x_tape"], names_r, k_loc, -1)
         d_loc = slice_dim(tapes["d_tape"], names_c, n_loc, -1)
+        s_loc = tapes["s_tape"]
         if noise is not None:
             noise = slice_dim(noise, names_r, k_loc, lead)
             noise = slice_dim(noise, names_c, n_loc, lead + 1)
@@ -617,6 +662,7 @@ class AnalogTrainStep:
             size_d = g_loc.shape[d]
             x_loc = slice_dim(x_loc, names_d, size_d, d)
             d_loc = slice_dim(d_loc, names_d, size_d, d)
+            s_loc = slice_dim(s_loc, names_d, size_d, d)
             if noise is not None:
                 noise = slice_dim(noise, names_d, size_d, d)
             assert registry.hoist_axis(kind, len(gshape)) in (d, None), (
@@ -625,6 +671,8 @@ class AnalogTrainStep:
                                 if i != d])) if lead > 1 else 1
             lead_off = lead_off + _flat_axis_index(mesh, names_d) \
                 * jnp.uint32(size_d * rest)
+        x_loc, d_loc, scale, one_pass = write_operands(
+            x_loc, d_loc, s_loc, scale, self.xcfg)
         g3, x3, d3, s1, n3, unflatten = registry.flatten_lead(
             kind, g_loc, x_loc, d_loc, scale, noise)
         offs = (lead_off,
@@ -645,7 +693,7 @@ class AnalogTrainStep:
         if used:
             # audit: allow RA103 -- metric-only psum of 0/1 counts: integer sums are order-exact, bit-identity unaffected
             railed = jax.lax.psum(railed, used)
-        return g_new, railed, float(np.prod(gshape))
+        return g_new, railed, float(np.prod(gshape)), one_pass
 
 
 def make_analog_sgd_step(cfg: ModelConfig, lr: float,

@@ -146,8 +146,9 @@ def test_expert_update_matches_per_expert_reference():
     moved = 0
     for layer in range(p["g"].shape[0]):
         for ex in range(p["g"].shape[1]):
-            dw = jnp.einsum("bk,bn->kn", t["x_tape"][layer, ex],
-                            t["d_tape"][layer, ex])
+            x_scale, d_scale = t["s_tape"][layer, ex]
+            dw = jnp.einsum("bk,bn->kn", t["x_tape"][layer, ex] * x_scale,
+                            t["d_tape"][layer, ex] * d_scale)
             want = apply_update(p["g"][layer, ex],
                                 -lr * dw * p["w_scale"][layer, ex], dev)
             np.testing.assert_allclose(nw["g"][layer, ex], want,
@@ -182,7 +183,9 @@ def test_shared_block_tapes_one_slot_per_application():
     new_state, _ = step(state, batch, jax.random.PRNGKey(9))
     p = params["shared_attn"]["wqkv"]
     dev = crossbar_from_model(cfg).device
-    dw = jnp.einsum("gtk,gtn->kn", t["x_tape"], t["d_tape"])
+    assert t["s_tape"].shape == (groups, 2)   # a scale pair per application
+    dw = jnp.einsum("gtk,gtn->kn", t["x_tape"] * t["s_tape"][:, :1, None],
+                    t["d_tape"] * t["s_tape"][:, 1:, None])
     want = apply_update(p["g"], -lr * dw * p["w_scale"], dev)
     np.testing.assert_allclose(new_state["params"]["shared_attn"]["wqkv"]["g"],
                                want, rtol=1e-4, atol=1e-6)

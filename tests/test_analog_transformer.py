@@ -46,7 +46,8 @@ def test_program_readout_roundtrip():
 
 def test_taped_matmul_semantics():
     """Forward = VMM, dx = MVM through the same conductances, and the tape
-    cotangents are exactly the quantised write-driver operands."""
+    cotangents are exactly the write drivers' integer codes and their
+    scales, whose products are the quantised operands."""
     cfg = crossbar_from_model(_cfg())
     key = jax.random.PRNGKey(1)
     w = 0.1 * jax.random.normal(key, (48, 80))
@@ -66,8 +67,11 @@ def test_taped_matmul_semantics():
         dx, mvm(dy, p["g"], p["ref"], p["w_scale"], cfg),
         rtol=1e-5, atol=1e-6)
     x_q, d_q = quantize_update_operands(x, dy, cfg)
-    np.testing.assert_allclose(grads["x_tape"], x_q, rtol=1e-6)
-    np.testing.assert_allclose(grads["d_tape"], d_q, rtol=1e-6)
+    x_scale, d_scale = grads["s_tape"]
+    for codes in (grads["x_tape"], grads["d_tape"]):
+        np.testing.assert_array_equal(codes, np.round(codes))
+    np.testing.assert_allclose(grads["x_tape"] * x_scale, x_q, rtol=1e-6)
+    np.testing.assert_allclose(grads["d_tape"] * d_scale, d_q, rtol=1e-6)
     # the dense (K, N) gradient is never formed
     assert float(jnp.max(jnp.abs(grads["g"]))) == 0.0
 
@@ -117,8 +121,9 @@ def test_update_routes_through_kernel_device_model():
         leaf = "wqkv" if name == "attn" else "w_upgate"
         for layer in range(sub[leaf]["g"].shape[0]):
             p, g, n = sub[leaf], gsub[leaf], nsub[leaf]
-            dw = jnp.einsum("bk,bn->kn", g["x_tape"][layer],
-                            g["d_tape"][layer])
+            x_scale, d_scale = g["s_tape"][layer]
+            dw = jnp.einsum("bk,bn->kn", g["x_tape"][layer] * x_scale,
+                            g["d_tape"][layer] * d_scale)
             want = apply_update(p["g"][layer],
                                 -lr * dw * p["w_scale"][layer], dev)
             np.testing.assert_allclose(n["g"][layer], want,

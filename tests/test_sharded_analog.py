@@ -135,6 +135,7 @@ def test_analog_container_specs_policy():
     assert specs["scale"] == P(None)
     assert specs["x_tape"] == P(None, None, "data")
     assert specs["d_tape"] == P(None, None, "model")
+    assert specs["s_tape"] == P(None, None)
     # expert-batched containers: expert dim over model (EP), row tiles
     # over the FSDP axes, columns replicated, per-expert scales with
     # their experts
@@ -146,6 +147,7 @@ def test_analog_container_specs_policy():
     assert especs["x_tape"] == P(None, "model", None, "data")
     assert especs["d_tape"] == P(None, "model", None, None)
     assert especs["scale"] == P(None, "model")
+    assert especs["s_tape"] == P(None, "model", None)
     # an expert count that doesn't divide the model axis degrades
     assert analog_container_pspec(sp_e, (2, 6, 64, 64), cfg, mesh, "g") \
         == P(None, None, "data", None)

@@ -138,21 +138,39 @@ def test_ra102_tape_in_grad_tree():
 
     # A conductance leaf sharing the tape site in the differentiated
     # tree: the symbolic-zero hoist failed.
-    diff = {"blocks": {"0": {"wq": {"x_tape": 1, "d_tape": 2, "g": 3}}}}
+    diff = {"blocks": {"0": {"wq": {"x_tape": 1, "d_tape": 2, "s_tape": 6,
+                                    "g": 3}}}}
     frozen = {"blocks": {"0": {"wq": {"g": 3, "ref": 4, "w_scale": 5}}}}
     hits = _rules_hit(check_tape_containment(diff, frozen, "fx"), "RA102")
     assert hits and "['g']" in hits[0].message
 
     # A frozen container missing its hoisted leaves is the dual failure.
     hits = _rules_hit(check_tape_containment(
-        {"wq": {"x_tape": 1, "d_tape": 2}},
+        {"wq": {"x_tape": 1, "d_tape": 2, "s_tape": 6}},
         {"wq": {"g": 3}}, "fx"), "RA102")
     assert hits and "missing" in hits[0].message
 
-    # The shipped shape passes.
-    assert check_tape_containment(
+    # Codes without their scale slot are no write operands.
+    hits = _rules_hit(check_tape_containment(
         {"wq": {"x_tape": 1, "d_tape": 2}},
-        {"wq": {"g": 3, "ref": 4, "w_scale": 5}}, "fx") == []
+        {"wq": {"g": 3, "ref": 4, "w_scale": 5}}, "fx"), "RA102")
+    assert hits and "s_tape" in hits[0].message
+
+    # Code tapes in another dtype than the one that holds the codes.
+    import jax.numpy as jnp
+    f32 = jnp.zeros((2, 3), jnp.float32)
+    hits = _rules_hit(check_tape_containment(
+        {"wq": {"x_tape": f32, "d_tape": f32, "s_tape": 6}},
+        {"wq": {"g": 3, "ref": 4, "w_scale": 5}}, "fx",
+        code_dtype=jnp.bfloat16), "RA102")
+    assert hits and "bfloat16" in hits[0].message
+
+    # The shipped shape passes.
+    bf16 = f32.astype(jnp.bfloat16)
+    assert check_tape_containment(
+        {"wq": {"x_tape": bf16, "d_tape": bf16, "s_tape": 6}},
+        {"wq": {"g": 3, "ref": 4, "w_scale": 5}}, "fx",
+        code_dtype=jnp.bfloat16) == []
 
 
 def test_ra103_collective_in_shard_map_body():

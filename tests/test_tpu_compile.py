@@ -93,17 +93,24 @@ def test_fused_read_compiles(one_chip, transpose, in_bits):
         assert KERNEL in c.as_text()
 
 
-@pytest.mark.parametrize("noise_mode", ["kernel", "none"])
-@pytest.mark.parametrize("update_mode", ["outer", "pulse_train"])
-def test_update_compiles(one_chip, update_mode, noise_mode):
+@pytest.mark.parametrize("update_mode,noise_mode,drives", [
+    pytest.param(u, n, dt, id=f"{u}-{n}{tag}")
+    for dt, tag in ((jnp.float32, ""), (jnp.bfloat16, "-codes"))
+    for u in ("outer", "pulse_train") for n in ("kernel", "none")])
+def test_update_compiles(one_chip, update_mode, noise_mode, drives):
     """The kernel-noise cases generate the epilogue's normals in-kernel
-    (counter PRNG + Box–Muller) over a whole 1024x1024 tile."""
-    s = lambda *shape: _spec(one_chip, shape)
-    c = _compile(lambda g, x, d, m, seed: xbar_outer_update_inline(
-        g, x, d, m, XCFG, seed=seed if noise_mode == "kernel" else None,
-        noise_mode=noise_mode, impl="pallas", update_mode=update_mode),
-        s(L, K, N), s(L, TOKENS, K), s(L, TOKENS, N), s(L),
-        _spec(one_chip, (), jnp.uint32))
+    (counter PRNG + Box–Muller) over a whole 1024x1024 tile.  The write
+    drivers' bfloat16 codes are contracted in one pass (two for pulse
+    trains), float32 drives at HIGHEST; under a float32 model's default
+    matmul precision, "highest", which the bfloat16 dots must not take
+    up."""
+    s = lambda *shape, dt=jnp.float32: _spec(one_chip, shape, dt)
+    with jax.default_matmul_precision("highest"):
+        c = _compile(lambda g, x, d, m, seed: xbar_outer_update_inline(
+            g, x, d, m, XCFG, seed=seed if noise_mode == "kernel" else None,
+            noise_mode=noise_mode, impl="pallas", update_mode=update_mode),
+            s(L, K, N), s(L, TOKENS, K, dt=drives),
+            s(L, TOKENS, N, dt=drives), s(L), s(dt=jnp.uint32))
     assert KERNEL in c.as_text()
 
 
@@ -209,9 +216,10 @@ MOE_NAMES = ("moe.route", "moe.dispatch", "moe.experts")
 def test_expert_kernels_compile_with_row_counts(one_chip, kernel):
     """The expert-batched read (its row counts an SMEM operand, a dynamic
     trip count over the token strips) and write (the counts a
-    scalar-prefetch operand of its index maps) for the 8 held experts'
-    [up | gate] grids, over a dropless 4096-row buffer: a read block of
-    4096 tokens fits the VMEM cap."""
+    scalar-prefetch operand of its index maps, its drives the write
+    drivers' bfloat16 codes) for the 8 held experts' [up | gate] grids,
+    over a dropless 4096-row buffer: a read block of 4096 tokens fits the
+    VMEM cap."""
     s = lambda *shape, dt=jnp.float32: _spec(one_chip, shape, dt)
     e, k, n = DS.n_experts, DS.d_model, 2 * DS.d_ff_expert
     rows = s(e, dt=jnp.int32)
@@ -219,8 +227,9 @@ def test_expert_kernels_compile_with_row_counts(one_chip, kernel):
         c = _compile(lambda g, x, d, m, seed, r: xbar_outer_update_inline(
             g, x, d, m, XCFG, seed=seed, noise_mode="kernel",
             impl="pallas", rows=r),
-            s(e, k, n), s(e, DS_TOKENS, k), s(e, DS_TOKENS, n), s(e),
-            s(dt=jnp.uint32), rows)
+            s(e, k, n), s(e, DS_TOKENS, k, dt=jnp.bfloat16),
+            s(e, DS_TOKENS, n, dt=jnp.bfloat16), s(e), s(dt=jnp.uint32),
+            rows)
     else:
         transpose = kernel == "mvm"
         c = _compile(lambda x, g, r, w, cnt: xbar_fused_read_inline(

@@ -1,18 +1,24 @@
 """The fused analog update path: layer-batched kernel equivalence,
+the one-pass contraction of the write drivers' codes and its fallback,
 hoisted symbolic-zero tapes, and the in-kernel counter PRNG."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import get_config
-from repro.core import (LINEARIZED, TAOX, AdcConfig, CrossbarConfig,
-                        weights_to_conductance)
+from repro.core import (IDEAL, LINEARIZED, TAOX, AdcConfig,
+                        CrossbarConfig, weights_to_conductance)
 from repro.core.tiled_analog import (is_analog_container, merge_tapes,
                                      split_tapes, with_tapes)
-from repro.core.xbar_ops import quantize_update_operands
+from repro.core.xbar_ops import (quantize_update_codes,
+                                 quantize_update_operands,
+                                 update_code_dtype)
 from repro.data.synthetic import batch_tokens, make_token_stream
-from repro.kernels.xbar_update import field_normals, xbar_outer_update
+from repro.kernels.xbar_update import (field_normals, write_one_pass,
+                                       write_operands, xbar_outer_update)
 from repro.models import model as M
 from repro.train.analog_lm import init_state, make_analog_sgd_step
 
@@ -240,6 +246,157 @@ def test_pulse_train_differs_from_outer_on_nonlinear_device():
     assert float(jnp.max(jnp.abs(a - b))) > 1e-4
 
 
+# ------------------------------------------- one-pass contraction of codes
+
+# An ideal device with a window no update reaches: from g = 0 the written
+# conductance is the requested update itself, so the accumulator shows
+# through the epilogue bit for bit.  Unit pulses make a pulse train's
+# event counts the accumulators themselves.
+OPEN = IDEAL.replace(gmin=-1e9, gmax=1e9, pulse_dg=1.0)
+
+
+def _tapes(reps=(), t=6, in_bits=8, upd_col_bits=4, lyr=3, k=40, n=24,
+           seed=0):
+    """Tapes as the train step's backward pass writes them: the write
+    drivers' codes in the dtype that holds them, and each application's
+    (x_scale, d_scale); with the dequantised operands of the same
+    activations and errors (``quantize_update_operands``) beside them."""
+    cfg = CrossbarConfig(rows=16, cols=16, device=OPEN,
+                         adc=AdcConfig(in_bits=in_bits),
+                         upd_col_bits=upd_col_bits)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 2)
+    x = jax.random.normal(keys[0], (lyr, *reps, t, k))
+    d = jax.random.normal(keys[1], (lyr, *reps, t, n)) * 0.2
+    per_matrix = lambda f: functools.reduce(
+        lambda h, _: jax.vmap(h), range(1 + len(reps)), f)
+    x_int, d_int, xs, ds = per_matrix(
+        lambda a, b: quantize_update_codes(a, b, cfg))(x, d)
+    x_q, d_q = per_matrix(
+        lambda a, b: quantize_update_operands(a, b, cfg))(x, d)
+    code = update_code_dtype(cfg)
+    tapes = (x_int.astype(code), d_int.astype(code),
+             jnp.stack([xs, ds], -1))
+    scale = -0.05 * jnp.linspace(0.5, 1.5, lyr, dtype=jnp.float32)
+    return cfg, tapes, (x_q, d_q), scale
+
+
+def _int_sum(a, b):
+    return np.einsum("lbk,lbn->lkn", np.asarray(a, np.float32)
+                     .astype(np.int64), np.asarray(b, np.float32)
+                     .astype(np.int64))
+
+
+@pytest.mark.parametrize("impl", ["fused", "interpret"])
+@pytest.mark.parametrize("mode", ["outer", "pulse_train"])
+def test_one_pass_accumulator_is_the_exact_integer_sum(impl, mode):
+    """8-bit row and 4-bit column codes go to the kernel in bfloat16, one
+    MXU pass, and its accumulators are the exact integer outer products:
+    the outer write is the folded scale times the int64 sum, bit for bit;
+    a pulse train at scale 2 with unit pulses fires acc + |acc|_abs SET
+    and |acc|_abs - acc RESET events, so with a unit noise field it
+    writes 2 acc + sqrt(2 a_abs), bit for bit."""
+    cfg, (x, d, s), _, scale = _tapes(t=64)
+    xb, db, m, one_pass = write_operands(x, d, s, scale, cfg)
+    assert one_pass and xb.dtype == db.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(m),
+                                  np.asarray(scale * (s[:, 0] * s[:, 1])))
+    acc = _int_sum(xb, db)
+    g = jnp.zeros(acc.shape, jnp.float32)
+    if mode == "outer":
+        out = xbar_outer_update(g, xb, db, m, cfg, impl=impl,
+                                noise_mode="none", block_b=16)
+        want = np.asarray(m)[:, None, None] * acc.astype(np.float32)
+    else:
+        a_abs = _int_sum(jnp.abs(xb), jnp.abs(db))
+        pcfg = cfg.replace(device=OPEN.replace(write_noise=1.0))
+        out = xbar_outer_update(g, xb, db, 2.0, pcfg, impl=impl,
+                                noise=jnp.ones_like(g), noise_mode="host",
+                                update_mode="pulse_train", block_b=16)
+        want = ((2 * acc).astype(np.float32)
+                + np.sqrt((2 * a_abs).astype(np.float32)))
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
+@pytest.mark.parametrize("operands", ["signed", "magnitude"])
+def test_one_pass_agrees_with_dequantised_highest_accumulator(operands):
+    """The old path contracted the dequantised operands in float32
+    (HIGHEST), rounding every product and partial sum; the new one scales
+    the exact integer sum once.  They agree within float32 rounding of a
+    T-term sum: (T + 3) unit roundoffs of |scale| sum |x_q| |d_q|.  The
+    magnitudes are the pulse train's second accumulator."""
+    t = 64
+    cfg, (x, d, s), (x_q, d_q), scale = _tapes(t=t)
+    if operands == "magnitude":
+        x, d, x_q, d_q = (jnp.abs(a) for a in (x, d, x_q, d_q))
+    xb, db, m, _ = write_operands(x, d, s, scale, cfg)
+    g = jnp.zeros((x.shape[0], x.shape[-1], d.shape[-1]), jnp.float32)
+    new = xbar_outer_update(g, xb, db, m, cfg, impl="fused",
+                            noise_mode="none")
+    old = xbar_outer_update(g, x_q, d_q, scale, cfg, impl="fused",
+                            noise_mode="none")
+    bound = (t + 3) * 2.0 ** -24 * np.abs(np.asarray(scale))[:, None, None] \
+        * np.einsum("lbk,lbn->lkn", np.abs(np.asarray(x_q, np.float64)),
+                    np.abs(np.asarray(d_q, np.float64)))
+    diff = np.abs(np.asarray(new, np.float64) - np.asarray(old, np.float64))
+    assert (diff <= bound).all()
+    assert diff.max() > 0      # the two really are different sums
+
+
+@pytest.mark.parametrize("case", ["upd_col_bits", "tokens", "reused"])
+def test_write_fallback_reproduces_dequantised_bits(case):
+    """Where one bfloat16 pass would not be exact — column codes past 2**8
+    (10-bit voltage coder), sums past 2**24 (9-bit coders over 260
+    tokens), or a reused container whose tapes hold a scale pair per
+    application — the write dequantises the codes to exactly the operands
+    ``quantize_update_operands`` gives and contracts them in float32, so
+    the written conductances are today's, bit for bit."""
+    kw = {"upd_col_bits": dict(upd_col_bits=10),
+          "tokens": dict(in_bits=9, upd_col_bits=9, t=260),
+          "reused": dict(reps=(2,))}[case]
+    cfg, (x, d, s), (x_q, d_q), scale = _tapes(**kw)
+    cfg = cfg.replace(device=TAOX)
+    assert update_code_dtype(cfg) == (jnp.float32 if case == "upd_col_bits"
+                                      else jnp.bfloat16)
+    assert write_one_pass(cfg, x.shape[-2]) == (case == "reused")
+    xf, df, m, one_pass = write_operands(x, d, s, scale, cfg)
+    assert not one_pass and xf.dtype == df.dtype == jnp.float32
+    np.testing.assert_array_equal(np.asarray(m), np.asarray(scale))
+    np.testing.assert_array_equal(np.asarray(xf), np.asarray(x_q))
+    np.testing.assert_array_equal(np.asarray(df), np.asarray(d_q))
+    # The reused container's applications add up in its token axis.
+    flat = lambda a: a.reshape(a.shape[0], -1, a.shape[-1])
+    g = jnp.full((x.shape[0], x.shape[-1], d.shape[-1]), 0.5, jnp.float32)
+    for impl in ("fused", "interpret"):
+        got = xbar_outer_update(g, flat(xf), flat(df), m, cfg,
+                                seed=jnp.uint32(5), noise_mode="kernel",
+                                impl=impl, block_b=16)
+        want = xbar_outer_update(g, flat(x_q), flat(d_q), scale, cfg,
+                                 seed=jnp.uint32(5), noise_mode="kernel",
+                                 impl=impl, block_b=16)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("arch,share", [("lm100m", "one"),
+                                        ("zamba2-1.2b", "part")])
+def test_write_one_pass_share(arch, share):
+    """The step's metric: every written cell of a dense decoder goes
+    through the one-pass contraction; a hybrid's shared block, one weight
+    set applied at every group boundary, tapes a scale pair per
+    application and takes the float32 fallback."""
+    cfg = get_config(arch, smoke=True).replace(
+        dtype="float32", analog=True, analog_mode="device",
+        analog_device="taox-nonoise", analog_rows=64, analog_cols=64)
+    step = make_analog_sgd_step(cfg, lr=0.05)
+    _, mets = step(init_state(jax.random.PRNGKey(0), cfg), _batch(cfg),
+                   jax.random.PRNGKey(1))
+    got = float(mets["write_one_pass_share"])
+    assert got == np.float32(step.write_one_pass_share)
+    if share == "one":
+        assert got == 1.0
+    else:
+        assert 0.0 < got < 1.0
+
+
 # --------------------------------------------------- hoisted symbolic tapes
 
 def test_split_merge_roundtrip():
@@ -269,7 +426,7 @@ def test_hoisted_grads_carry_only_tapes_for_containers():
 
     def walk(p, g):
         if is_analog_container(p):
-            assert set(g) == {"x_tape", "d_tape"}
+            assert set(g) == {"x_tape", "d_tape", "s_tape"}
         elif isinstance(p, dict):
             for k in p:
                 walk(p[k], g[k])
